@@ -1,1 +1,1 @@
-from repro import compat  # noqa: F401  (jax forward-compat aliases)
+"""Extreme-classification training and serving (KDD 2020, Alibaba)."""

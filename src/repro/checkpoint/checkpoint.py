@@ -6,43 +6,27 @@ already keys leaves by tree path, so per-shard files compose). Restore takes
 a ``target`` template pytree (params/opt-state structure with NamedTuples)
 and refills its leaves, preserving shardings via device_put-like placement by
 the caller.
-
-zstd is optional: containers without the ``zstandard`` wheel fall back to
-stdlib zlib. Restore sniffs the frame magic, so either side can read files
-written by the other.
 """
 from __future__ import annotations
 
 import os
 import re
-import zlib
 from typing import Any, Optional
 
 import jax
 import msgpack
 import numpy as np
-
-try:
-    import zstandard
-except ImportError:          # container without the wheel: stdlib fallback
-    zstandard = None
+import zstandard
 
 _ZSTD_MAGIC = b"\x28\xb5\x2f\xfd"
 
 
 def _compress(raw: bytes) -> bytes:
-    if zstandard is not None:
-        return zstandard.ZstdCompressor(level=3).compress(raw)
-    return zlib.compress(raw, level=6)
+    return zstandard.ZstdCompressor(level=3).compress(raw)
 
 
 def _decompress(blob: bytes) -> bytes:
-    if blob[:4] == _ZSTD_MAGIC:
-        if zstandard is None:
-            raise RuntimeError("checkpoint is zstd-compressed but the "
-                               "'zstandard' module is unavailable")
-        return zstandard.ZstdDecompressor().decompress(blob)
-    return zlib.decompress(blob)
+    return zstandard.ZstdDecompressor().decompress(blob)
 
 
 def _key_str(path) -> str:

@@ -64,6 +64,44 @@ def _merge_topk(best_v, best_i, new_v, new_i, k):
     return top_v, jnp.take_along_axis(i, pos, axis=1)
 
 
+# Tiles of the ring build at a chip's share of the paper deployment
+# (N_loc = 390,656 rows, D = 512), where the untiled pass 1 and pass 2 would
+# need 610 GB and 25.6 GB of HBM: pass 1 (ref) scores [ROW_CHUNK, COL_CHUNK]
+# f32 tiles (0.5 GB); pass 2 gathers [ROW_CHUNK, k'=32, D] f32 (0.5 GB).
+ROW_CHUNK = 8192
+COL_CHUNK = 16384
+# Pass 1 (ref) sorts only the k' best GROUP-column groups of a tile: a
+# lax.top_k on TPU sorts its whole input, and the sort is the cost.
+GROUP = 16
+
+
+def _chunk_rows(x, size: int, fill):
+    """[N, ...] -> [ceil(N/size), size, ...], padding with ``fill``."""
+    pad = (-x.shape[0]) % size
+    if pad:
+        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1),
+                    constant_values=fill)
+    return x.reshape((-1, size) + x.shape[1:])
+
+
+def _merge_tile_topk(bv, bi, scores, cid, kprime: int, group: int):
+    """Merge a [R, C] score tile (column ids ``cid`` [C]) into the running
+    top-k' (bv, bi) [R, k'], with the result of one ``lax.top_k`` over the
+    concatenation. A row's k' best scores of the tile lie in its k' column
+    groups with the largest maxima (ties go to the lower group, and groups
+    are contiguous columns), so only those groups, in column order, are
+    sorted."""
+    r, c = scores.shape
+    n_groups = c // group
+    tiles = scores.reshape(r, n_groups, group)
+    _, top = jax.lax.top_k(jnp.max(tiles, axis=2), min(kprime, n_groups))
+    top = jnp.sort(top, axis=1)
+    cand = jnp.take_along_axis(tiles, top[:, :, None], axis=1)
+    ids = jnp.take(cid.reshape(n_groups, group), top, axis=0)
+    return _merge_topk(bv, bi, cand.reshape(r, -1), ids.reshape(r, -1),
+                       kprime)
+
+
 def ring_knn_local(w_loc, *, k: int, kprime: int, model_axis: str, n_shards: int,
                    compute_dtype=jnp.bfloat16, backend: str = "ref"):
     """shard_map body: exact KNN of the full W from per-device blocks.
@@ -74,7 +112,10 @@ def ring_knn_local(w_loc, *, k: int, kprime: int, model_axis: str, n_shards: int
 
     ``backend="pallas"`` fuses each hop's score + top-k' through the
     ``kernels.ops.dist_topk`` kernel (the [N_loc, N_loc] score tile stays in
-    VMEM); ``ref`` keeps the einsum + merge-sweep formulation.
+    VMEM); ``ref`` scores [ROW_CHUNK, COL_CHUNK] tiles of the hop and merges
+    each into the running top-k' (``_merge_tile_topk``). Pass 2 gathers the
+    candidates ``ROW_CHUNK`` rows at a time. Neither pass ever holds an
+    [N_loc, N_loc] or [N_loc, k', D] array.
     """
     n_loc, d = w_loc.shape
     wn = w_loc.astype(jnp.float32)
@@ -82,6 +123,9 @@ def ring_knn_local(w_loc, *, k: int, kprime: int, model_axis: str, n_shards: int
     w16 = wn.astype(compute_dtype)
     my = jax.lax.axis_index(model_axis)
     perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]
+    group = min(GROUP, n_loc)
+    col_chunk = -(-min(COL_CHUNK, n_loc) // group) * group
+    row_chunk = min(ROW_CHUNK, n_loc)
 
     # ---- pass 1: bf16 scoring, running top-k' ---------------------------
     def hop(step, carry):
@@ -91,17 +135,34 @@ def ring_knn_local(w_loc, *, k: int, kprime: int, model_axis: str, n_shards: int
             # fused score + per-hop top-k'; the traveling block's local ids
             # are shifted to global AFTER the kernel (src is traced, block
             # geometry is static)
-            hv, hi = ops.dist_topk(w16, block, kprime,
-                                   block_q=min(128, n_loc),
-                                   block_n=min(128, n_loc))
+            hv, hi = ops.dist_topk(w16, block, kprime, block_q=256,
+                                   block_n=512)
             hi = jnp.where(hi >= 0, hi + src * n_loc, -1)
             bv, bi = _merge_topk(bv, bi, hv, hi, kprime)
         else:
-            scores = jnp.einsum("nd,md->nm", w16, block,
-                                preferred_element_type=jnp.float32)
-            ids = (src * n_loc + jnp.arange(n_loc, dtype=jnp.int32))[None, :]
-            ids = jnp.broadcast_to(ids, scores.shape)
-            bv, bi = _merge_topk(bv, bi, scores, ids, kprime)
+            ids = jnp.arange(n_loc, dtype=jnp.int32) + src * n_loc
+            blocks = _chunk_rows(block, col_chunk, 0)
+            cids = _chunk_rows(ids, col_chunk, -1)      # -1 = padding
+
+            def rows(xs):
+                w_r, v_r, i_r = xs              # [R, D], [R, k'], [R, k']
+
+                def tile(carry, ys):
+                    blk, cid = ys               # [C, D], [C]
+                    s = jnp.einsum("nd,md->nm", w_r, blk,
+                                   precision=jax.lax.Precision.DEFAULT,
+                                   preferred_element_type=jnp.float32)
+                    s = jnp.where(cid[None, :] >= 0, s, -jnp.inf)
+                    return _merge_tile_topk(*carry, s, cid, kprime,
+                                            group), None
+
+                return jax.lax.scan(tile, (v_r, i_r), (blocks, cids))[0]
+
+            bv, bi = jax.lax.map(rows, (_chunk_rows(w16, row_chunk, 0),
+                                        _chunk_rows(bv, row_chunk, -jnp.inf),
+                                        _chunk_rows(bi, row_chunk, -1)))
+            bv = bv.reshape(-1, kprime)[:n_loc]
+            bi = bi.reshape(-1, kprime)[:n_loc]
         block = jax.lax.ppermute(block, model_axis, perm)
         return block, bv, bi
 
@@ -116,11 +177,15 @@ def ring_knn_local(w_loc, *, k: int, kprime: int, model_axis: str, n_shards: int
     def hop32(step, carry):
         block, acc = carry
         src = (my - step) % n_shards
-        lo = src * n_loc
-        rel = bi - lo                       # candidate position in this block
+        rel = bi - src * n_loc              # candidate position in this block
         here = (rel >= 0) & (rel < n_loc)
-        cand = block[jnp.clip(rel, 0, n_loc - 1)]       # [N_loc, k', D] fp32
-        s = jnp.einsum("nd,nkd->nk", wn, cand)
+
+        def rows(xs):
+            w_r, rel_r = xs                 # [D], [k']
+            cand = block[jnp.clip(rel_r, 0, n_loc - 1)]     # [k', D]
+            return jnp.dot(cand, w_r, precision=jax.lax.Precision.HIGHEST)
+
+        s = jax.lax.map(rows, (wn, rel), batch_size=row_chunk)
         acc = jnp.where(here, s, acc)
         block = jax.lax.ppermute(block, model_axis, perm)
         return block, acc

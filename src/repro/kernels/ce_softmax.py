@@ -82,7 +82,7 @@ def _fwd_kernel(lim_ref, f_ref, w_ref, y_ref,
 
 
 def ce_forward(f, w, y, *, limit=None, block_v: int = 512,
-               scale: float = 1.0, interpret: bool = True):
+               scale: float = 1.0, interpret: bool):
     """f [B,D], w [V,D], y [B] local ids (out-of-range = not owned).
 
     ``limit`` (traced int scalar, default V) masks columns >= limit out of
@@ -145,8 +145,10 @@ def _bwd_kernel(lim_ref, f_ref, w_ref, y_ref, m_ref, gz_ref, gc_ref,
     y = y_ref[...]
     col = j * bv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     valid = col < lim_ref[0]
-    p = jnp.where(valid & jnp.isfinite(m)[:, None],
-                  jnp.exp(s - m[:, None]), 0.0)       # [B, bv] exp rel. to m
+    # reshape m before the test: Mosaic cannot reshape an i1 vector
+    m = m[:, None]
+    p = jnp.where(valid & jnp.isfinite(m),
+                  jnp.exp(s - m), 0.0)                # [B, bv] exp rel. to m
     hit = (col == y[:, None]).astype(jnp.float32)
     dl = (p * gz[:, None] + hit * gc[:, None]) * scale
     dw_ref[...] = jax.lax.dot_general(
@@ -162,8 +164,7 @@ def _bwd_kernel(lim_ref, f_ref, w_ref, y_ref, m_ref, gz_ref, gc_ref,
 
 
 def ce_backward(f, w, y, m, gz, gc, *, limit=None,
-                block_v: int = 512, scale: float = 1.0,
-                interpret: bool = True):
+                block_v: int = 512, scale: float = 1.0, interpret: bool):
     """Streamed backward from per-row cotangents.
 
     m is the forward's per-row running max (residual); gz / gc are the

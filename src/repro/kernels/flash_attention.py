@@ -12,9 +12,9 @@ with the kv dimension innermost; VMEM scratch carries the online-softmax
 Causality lets the sweep skip nothing here (masked tiles still counted) —
 block-level skipping is a further ~2x (documented, not implemented).
 
-This container is CPU-only: the kernel is validated in interpret mode
-against the pure-jnp oracle; the GSPMD dry-run keeps the jnp path because
-Pallas cannot lower for TPU on a CPU backend.
+Off a TPU the kernel runs in the Pallas interpreter (``ops.use_interpreter``)
+and is validated against the pure-jnp oracle; the GSPMD dry-run keeps the
+jnp path because Pallas cannot lower for TPU on a CPU backend.
 """
 from __future__ import annotations
 
@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.ops import use_interpreter
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr, *,
@@ -71,8 +73,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr, *,
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    block_q: int = 128, block_kv: int = 128,
-                    interpret: bool = True):
+                    block_q: int = 128, block_kv: int = 128):
     """q: [BH, Sq, Dh]; k, v: [BH, T, Dh] -> [BH, Sq, Dh].
 
     GQA is handled by the caller repeating/reshaping heads into BH.
@@ -101,6 +102,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32)],
-        interpret=interpret,
+        interpret=use_interpreter(),
     )(q, k, v)
     return out[:, :sq]

@@ -1,8 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only (TPU v5e is
-the compile target); on real TPU pass interpret=False (or set
-REPRO_PALLAS_INTERPRET=0).
+The kernels compile through Mosaic on a TPU backend and run in the Pallas
+interpreter on every other backend (``use_interpreter``), so the CPU tests
+check the same kernel bodies the chip runs.
 
 The two CE entry points the heads consume are ``ce_shard_stats`` (dense
 vocab-shard sweep) and ``sparse_ce_stats`` (active-class gather + CE). Both
@@ -18,7 +18,6 @@ backward kernels can ignore its cotangent and still be exact.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +28,10 @@ from repro.kernels import knn_dist_topk as _dk
 from repro.kernels import sparse_ce as _sp
 from repro.kernels import topk_dc as _dc
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+
+def use_interpreter() -> bool:
+    """Mosaic compiles the kernels only for a TPU; elsewhere interpret."""
+    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -50,7 +52,7 @@ def topk_dc(x: jax.Array, k: int, *, chunk: int = 2048, block_rows: int = 8):
     chunks = xp.reshape(-1, chunk)
     kk = min(k, chunk)
     sub_v, sub_i = _dc.stage1_topk(chunks, kk, block_rows=block_rows,
-                                   interpret=INTERPRET)        # stage 1
+                                   interpret=use_interpreter())  # stage 1
     base = (jnp.arange(chunks.shape[0], dtype=jnp.int32) * chunk)[:, None]
     flat_v = sub_v.reshape(-1)
     flat_i = (sub_i + base).reshape(-1)
@@ -77,7 +79,7 @@ def topk_rows(x: jax.Array, k: int, *, chunk: int = 2048,
     kk = min(k, n)
     if n <= chunk:
         vals, ids = _dc.stage1_topk(x, kk, block_rows=block_rows,
-                                    interpret=INTERPRET)
+                                    interpret=use_interpreter())
         return vals[:, :kk], ids[:, :kk]
     pad = (-n) % chunk
     xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, pad)),
@@ -86,7 +88,7 @@ def topk_rows(x: jax.Array, k: int, *, chunk: int = 2048,
     chunks = xp.reshape(b * nch, chunk)
     kc = min(kk, chunk)
     sub_v, sub_i = _dc.stage1_topk(chunks, kc, block_rows=block_rows,
-                                   interpret=INTERPRET)
+                                   interpret=use_interpreter())
     base = (jnp.arange(nch, dtype=jnp.int32) * chunk)[None, :, None]
     flat_v = sub_v.reshape(b, nch * kc)
     flat_i = (sub_i.reshape(b, nch, kc) + base).reshape(b, nch * kc)
@@ -103,7 +105,7 @@ def ivf_rerank(f, w, cand, k: int, *, block_a: int = 128):
     has fewer than k candidates). Neither the gathered [A, D] weights nor
     the [B, A] scores reach HBM."""
     return _ir.ivf_rerank(f, w, cand, k, block_a=block_a,
-                          interpret=INTERPRET)
+                          interpret=use_interpreter())
 
 
 # ---------------------------------------------------------------------------
@@ -116,7 +118,7 @@ def ivf_rerank(f, w, cand, k: int, *, block_a: int = 128):
 def dist_topk(q: jax.Array, kmat: jax.Array, kprime: int, *,
               block_q: int = 128, block_n: int = 128, col_offset: int = 0):
     return _dk.dist_topk(q, kmat, kprime, block_q=block_q, block_n=block_n,
-                         col_offset=col_offset, interpret=INTERPRET)
+                         col_offset=col_offset, interpret=use_interpreter())
 
 
 # ---------------------------------------------------------------------------
@@ -132,12 +134,13 @@ def ce_shard_stats(f, w, y, limit, scale: float = 1.0, block_v: int = 512):
     masks columns >= limit (Megatron vocab padding). The [B, V] logit tensor
     never materializes; m and amax are non-differentiable statistics."""
     return _ce.ce_forward(f, w, y, limit=limit, scale=scale, block_v=block_v,
-                          interpret=INTERPRET)
+                          interpret=use_interpreter())
 
 
 def _ce_shard_fwd(f, w, y, limit, scale, block_v):
     m, z, corr, amax = _ce.ce_forward(f, w, y, limit=limit, scale=scale,
-                                      block_v=block_v, interpret=INTERPRET)
+                                      block_v=block_v,
+                                      interpret=use_interpreter())
     return (m, z, corr, amax), (f, w, y, limit, m)
 
 
@@ -145,7 +148,7 @@ def _ce_shard_bwd(scale, block_v, res, cts):
     f, w, y, limit, m = res
     _, gz, gc, _ = cts          # gm / gamax ignored: exact (see module doc)
     df, dw = _ce.ce_backward(f, w, y, m, gz, gc, limit=limit, scale=scale,
-                             block_v=block_v, interpret=INTERPRET)
+                             block_v=block_v, interpret=use_interpreter())
     return df.astype(f.dtype), dw.astype(w.dtype), None, None
 
 
@@ -169,7 +172,7 @@ def fused_ce(f, w, y, scale: float = 1.0, block_v: int = 512):
 def fused_ce_stats(f, w, y, *, scale: float = 1.0, block_v: int = 512):
     """(m, z, corr) building blocks for the distributed (sharded) loss."""
     m, z, corr, _ = _ce.ce_forward(f, w, y, scale=scale, block_v=block_v,
-                                   interpret=INTERPRET)
+                                   interpret=use_interpreter())
     return m, z, corr
 
 
@@ -195,14 +198,15 @@ def sparse_ce_stats(f, w, ids, gids, bias, valid, y, scale: float = 1.0,
     scatter-added into the shard here."""
     return _sp.sparse_ce_forward(f, w, ids, gids, bias, valid, y,
                                  scale=scale, block_a=block_a,
-                                 mask_hits=mask_hits, interpret=INTERPRET)
+                                 mask_hits=mask_hits,
+                                 interpret=use_interpreter())
 
 
 def _sparse_ce_fwd(f, w, ids, gids, bias, valid, y, scale, block_a,
                    mask_hits):
     m, z, corr, amax = _sp.sparse_ce_forward(
         f, w, ids, gids, bias, valid, y, scale=scale, block_a=block_a,
-        mask_hits=mask_hits, interpret=INTERPRET)
+        mask_hits=mask_hits, interpret=use_interpreter())
     return (m, z, corr, amax), (f, w, ids, gids, bias, valid, y, m)
 
 
@@ -211,7 +215,7 @@ def _sparse_ce_bwd(scale, block_a, mask_hits, res, cts):
     _, gz, gc, _ = cts          # gm / gamax ignored: exact (see module doc)
     df, dwa = _sp.sparse_ce_backward(
         f, w, ids, gids, bias, valid, y, m, gz, gc, scale=scale,
-        block_a=block_a, mask_hits=mask_hits, interpret=INTERPRET)
+        block_a=block_a, mask_hits=mask_hits, interpret=use_interpreter())
     safe = jnp.clip(ids.astype(jnp.int32), 0, w.shape[0] - 1)
     dw = jnp.zeros(w.shape, jnp.float32).at[safe].add(dwa)
     return (df.astype(f.dtype), dw.astype(w.dtype), None, None, None, None,

@@ -35,7 +35,7 @@ def _stage1_kernel(x_ref, vals_ref, idx_ref, *, k: int):
 
 
 def stage1_topk(chunks: jax.Array, k: int, *, block_rows: int = 8,
-                interpret: bool = True):
+                interpret: bool):
     """chunks: [M, C] -> (vals [M, k] fp32 desc-sorted, idx [M, k] int32)."""
     m, c = chunks.shape
     pad_m = (-m) % block_rows

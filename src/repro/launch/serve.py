@@ -134,8 +134,9 @@ def main(argv=None):
     if args.max_wait_ms < 0:
         p.error(f"--max-wait-ms must be >= 0, got {args.max_wait_ms}")
 
-    from repro.api.bootstrap import ensure_host_devices
+    from repro.api.bootstrap import enable_compile_cache, ensure_host_devices
     ensure_host_devices(args.devices)
+    enable_compile_cache()
     from repro.telemetry import Tracer
 
     # one tracer for the whole run: the timings printed below are the

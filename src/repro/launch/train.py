@@ -101,8 +101,9 @@ def parse_args(argv=None):
 
 def main(argv=None):
     args = parse_args(argv)
-    from repro.api.bootstrap import ensure_host_devices
+    from repro.api.bootstrap import enable_compile_cache, ensure_host_devices
     ensure_host_devices(args.devices)
+    enable_compile_cache()
 
     from repro.api import Experiment
     from repro.configs.base import (DGCConfig, FCCSConfig, HeadConfig,

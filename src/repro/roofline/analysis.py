@@ -14,8 +14,9 @@ The ratio MODEL_FLOPS / HLO_FLOPs measures how much compiled compute is
 useful — it surfaces remat recompute, replicated attention heads, dropped/
 padded expert capacity, and the head's logits work.
 
-Hardware constants (TPU v5e-class target, per chip):
-    197 TFLOP/s bf16 | 819 GB/s HBM | ~50 GB/s/link ICI
+Per-chip peaks come from ``PEAKS``, keyed by the ``device_kind`` that jax
+reports. The dry run lowers for a v5e pod, so its records are read against
+the v5e row unless they name another kind.
 """
 from __future__ import annotations
 
@@ -26,9 +27,24 @@ from typing import Optional
 from repro.configs.base import (INPUT_SHAPES, ModelConfig, get_model_config,
                                 normalize_arch_id)
 
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # bytes/s / chip
-ICI_BW = 50e9              # bytes/s / link (per-device collective throughput)
+# Published per-chip peaks by ``jax.devices()[i].device_kind``. Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at
+# 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 200e9,
+                    "hbm_bytes": 16e9},
+}
+DRYRUN_DEVICE_KIND = "TPU v5 lite"   # the chip launch/dryrun.py lowers for
+
+
+def peaks(device_kind: str) -> dict:
+    """Per-chip peaks of ``device_kind``; a kind without published figures
+    in ``PEAKS`` is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 # ---------------------------------------------------------------------------
@@ -118,12 +134,13 @@ def analyze_record(rec: dict) -> Optional[RooflineRow]:
     if "error" in rec:
         return None
     n_chips = 512 if rec["mesh"] == "2x16x16" else 256
+    chip = peaks(rec.get("device_kind", DRYRUN_DEVICE_KIND))
     flops_dev = rec["hlo"]["flops"]
     bytes_dev = rec["hlo"]["bytes"]
     coll_dev = rec["collectives"]["total_bytes"]
-    compute_s = flops_dev / PEAK_FLOPS
-    memory_s = bytes_dev / HBM_BW
-    coll_s = coll_dev / ICI_BW
+    compute_s = flops_dev / chip["flops"]
+    memory_s = bytes_dev / chip["hbm_bw"]
+    coll_s = coll_dev / chip["ici_bw"]
     dominant = max(
         (("compute", compute_s), ("memory", memory_s),
          ("collective", coll_s)), key=lambda kv: kv[1])[0]
@@ -138,7 +155,7 @@ def analyze_record(rec: dict) -> Optional[RooflineRow]:
         n_chips=n_chips, compute_s=compute_s, memory_s=memory_s,
         collective_s=coll_s, dominant=dominant, model_flops=mf,
         hlo_flops_per_dev=flops_dev, useful_ratio=useful,
-        peak_gib=per_dev / 2**30, fits=per_dev <= 16 * 2**30)
+        peak_gib=per_dev / 2**30, fits=per_dev <= chip["hbm_bytes"])
 
 
 def load_rows(path: str, mesh: Optional[str] = None):

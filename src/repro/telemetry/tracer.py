@@ -110,8 +110,8 @@ class Tracer:
         self.gauges[name] = value
 
     def record_peak_memory(self, prefix: str = "mem.peak_bytes") -> dict:
-        """Gauge the current peak-memory watermark per device (host RSS
-        fallback on backends without ``memory_stats``)."""
+        """Gauge the current peak-memory watermark per device (host RSS on
+        the CPU backend; see ``device_peak_memory``)."""
         peaks = device_peak_memory()
         for dev, b in peaks.items():
             self.gauge(f"{prefix}.{dev}", b)
@@ -219,21 +219,25 @@ NULL_TRACER = NullTracer()
 
 
 def device_peak_memory() -> dict:
-    """Peak-memory watermark per jax device (``memory_stats`` where the
-    backend reports it — TPU/GPU), with the process high-water RSS as the
-    host fallback (this CPU container's fake devices share one heap)."""
+    """Peak-memory watermark per jax device from its ``memory_stats``.
+
+    The CPU backend keeps no device stats, and its fake host devices share
+    one heap: there the process high-water RSS is reported, under
+    ``host_rss``. On any other backend a device without
+    ``peak_bytes_in_use`` is an error, never a host number."""
     import jax
 
-    peaks = {}
-    for d in jax.devices():
-        try:
-            stats = d.memory_stats()
-        except Exception:  # noqa: BLE001 — CPU backend has no stats
-            stats = None
-        if stats and "peak_bytes_in_use" in stats:
-            peaks[str(d.id)] = int(stats["peak_bytes_in_use"])
-    if not peaks:
+    devices = jax.devices()
+    if devices[0].platform == "cpu":
         import resource
-        peaks["host_rss"] = (
-            resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024)
+        return {"host_rss":
+                resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024}
+    peaks = {}
+    for d in devices:
+        stats = d.memory_stats() or {}
+        if "peak_bytes_in_use" not in stats:
+            raise RuntimeError(f"{d.platform} device {d.id} "
+                               f"({d.device_kind}) reports no "
+                               f"peak_bytes_in_use in memory_stats()")
+        peaks[str(d.id)] = int(stats["peak_bytes_in_use"])
     return peaks

@@ -44,9 +44,11 @@ AXIS = "hybrid"
 
 
 def make_hybrid_mesh(n_dev: Optional[int] = None):
+    """The 1-D ring over the first ``n_dev`` devices (default: all)."""
     n = n_dev or len(jax.devices())
     return jax.make_mesh((n,), (AXIS,),
-                         axis_types=(jax.sharding.AxisType.Auto,))
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=jax.devices()[:n])
 
 
 class HybridState(NamedTuple):
@@ -114,6 +116,16 @@ def state_specs(state: HybridState, head: SoftmaxHead):
             v=jax.tree.map(lambda _: P(AXIS), state.dgc.v))
     return HybridState(fe_spec, hp_spec, head.aux_spec(AXIS), opt_spec,
                        dgc_spec, P())
+
+
+def place_state(state: HybridState, head: SoftmaxHead, mesh) -> HybridState:
+    """Put every leaf of ``state`` on ``mesh`` with its ring spec, as the
+    train step returns it, so the first step and the later ones share one
+    compiled program."""
+    from jax.sharding import NamedSharding
+
+    return jax.tree.map(lambda a, s: jax.device_put(a, NamedSharding(mesh, s)),
+                        state, state_specs(state, head))
 
 
 def _flat_features_and_labels(model_cfg, fe_params, micro_inputs):

@@ -71,9 +71,9 @@ class PaperTrainer:
         n_dev = self.mesh.shape[hybrid.AXIS]
         self.n_dev = n_dev
         self.head = make_head(self.model_cfg, self.head_cfg)
-        self.state = hybrid.init_state(
+        self.state = hybrid.place_state(hybrid.init_state(
             jax.random.PRNGKey(self.seed), self.model_cfg, self.head_cfg,
-            self.train_cfg, n_dev, head=self.head)
+            self.train_cfg, n_dev, head=self.head), self.head, self.mesh)
         self._steps = {}
         self._t = 0          # data cursor: next step index run() will take
         self.restores = 0    # bumped on every restore (serving-cache probe)

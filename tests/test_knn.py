@@ -27,11 +27,24 @@ def problem():
             jax.random.randint(ky, (B,), 0, N))
 
 
-def test_ring_build_is_exact(mesh2x4, problem):
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+@pytest.mark.parametrize("tiles,k,kprime", [
+    (None, 8, 16),
+    ((8, 5, 2), 8, 16),     # 2 column chunks per hop, row remainder
+    ((8, 5, 2), 2, 3),      # 4 groups per chunk: the group prefilter picks 3
+])
+def test_ring_build_is_exact(mesh2x4, problem, monkeypatch, tiles, k, kprime,
+                             backend):
+    """Exact with whole-shard tiles and with (COL_CHUNK, ROW_CHUNK, GROUP)
+    tiles that split the 16-row shard and leave remainders."""
+    if tiles is not None:
+        for name, size in zip(("COL_CHUNK", "ROW_CHUNK", "GROUP"), tiles):
+            monkeypatch.setattr(kg, name, size)
     _, w, _ = problem
-    g_ref = kg.knn_graph_ref(w, 8)
+    g_ref = kg.knn_graph_ref(w, k)
     w_sh = jax.device_put(w, NamedSharding(mesh2x4, P("model", None)))
-    g = np.asarray(kg.build_graph_distributed(mesh2x4, w_sh, k=8, kprime=16))
+    g = np.asarray(kg.build_graph_distributed(mesh2x4, w_sh, k=k,
+                                              kprime=kprime, backend=backend))
     assert (np.sort(g, 1) == np.sort(np.asarray(g_ref), 1)).all()
 
 

@@ -228,3 +228,34 @@ def test_microbatched_grads_equal_full_batch():
     assert abs(float(l1) - float(l4)) < 1e-6
     np.testing.assert_allclose(np.asarray(g1["w"]), np.asarray(g4["w"]),
                                atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# process bootstrap: persistent compilation cache
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """An env-named cache directory is JAX's own business (nothing is set
+    in code); without one the cache sits at the fixed <repo>/.jax_cache."""
+    import os
+
+    from repro.api import bootstrap
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        got = bootstrap.enable_compile_cache()
+        if env_dir is None:
+            assert got == os.path.join(bootstrap.REPO_ROOT, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            assert os.path.isfile(os.path.join(bootstrap.REPO_ROOT,
+                                               "chip_smoke.py"))
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -24,9 +24,10 @@ def _fake_record(flops=1e12, nbytes=1e9, coll=1e7, mesh="16x16"):
 
 def test_analyze_record_terms():
     row = an.analyze_record(_fake_record())
-    assert row.compute_s == pytest.approx(1e12 / an.PEAK_FLOPS)
-    assert row.memory_s == pytest.approx(1e9 / an.HBM_BW)
-    assert row.collective_s == pytest.approx(1e7 / an.ICI_BW)
+    v5e = an.peaks("TPU v5 lite")
+    assert row.compute_s == pytest.approx(1e12 / 197e12)
+    assert row.memory_s == pytest.approx(1e9 / 819e9)
+    assert row.collective_s == pytest.approx(1e7 / v5e["ici_bw"])
     assert row.dominant == "compute"
     assert row.n_chips == 256
     assert row.fits  # 2 + 5 GiB < 16
@@ -37,6 +38,13 @@ def test_analyze_record_dominance_switch():
     assert row.dominant == "memory"
     row = an.analyze_record(_fake_record(flops=1.0, coll=1e13))
     assert row.dominant == "collective"
+
+
+def test_unknown_device_kind_is_an_error():
+    with pytest.raises(ValueError, match="no published peaks"):
+        an.peaks("cpu")
+    with pytest.raises(ValueError, match="no published peaks"):
+        an.analyze_record(dict(_fake_record(), device_kind="TPU v4"))
 
 
 def test_analyze_record_skips_errors():
