@@ -1,0 +1,20 @@
+"""ce_kernel_roofline (%): the least time of the fused CE forward and
+backward kernels over the window's micro-batches (per micro-batch the
+larger of their operations over the bf16 peak and their bytes over the
+HBM bandwidth, ``bench/flops.ce_kernels``) over their device time in the
+trace: the Pallas kernels of the train step program."""
+from bench import flops
+
+
+def read(run):
+    f, s = run.facts, run.trace_summary
+    if s is None or not f.get("micro_batches"):
+        return None
+    t = s.op_seconds(lambda o: o.is_kernel and "step" in o.hlo_module)
+    if t <= 0:
+        return None
+    peak = flops.peaks(run.devs[0].device_kind)
+    b = f["micro_batch"] * f["chips"]
+    least, _ = flops.bound_s(*flops.ce_kernels(b, f["classes_per_chip"],
+                                               f["d"]), peak)
+    return 100.0 * least * f["micro_batches"] / t
