@@ -46,6 +46,7 @@ from repro.core.knn_softmax import knn_softmax_local
 from repro.core.sharded_softmax import (_normalize, full_softmax_local,
                                         serve_argmax_local,
                                         serve_logits_local)
+from repro.telemetry import NULL_TRACER
 
 
 class HeadState(NamedTuple):
@@ -167,9 +168,11 @@ class SoftmaxHead:
         """Steps between ``refresh`` calls; 0 = no periodic work."""
         return 0
 
-    def refresh(self, mesh, head_state: HeadState, *,
-                model_axis) -> HeadState:
-        """Rebuild aux state from the current params (no-op by default)."""
+    def refresh(self, mesh, head_state: HeadState, *, model_axis,
+                telemetry=None) -> HeadState:
+        """Rebuild aux state from the current params (no-op by default).
+        ``telemetry`` (a ``Tracer``) receives the rebuild's
+        ``train.refresh.*`` spans."""
         return head_state
 
     # -- shared helpers ---------------------------------------------------
@@ -271,21 +274,27 @@ class KNNSoftmaxHead(FullSoftmaxHead):
     def refresh_every(self) -> int:
         return self.head_cfg.rebuild_every
 
-    def refresh(self, mesh, head_state: HeadState, *,
-                model_axis) -> HeadState:
+    def refresh(self, mesh, head_state: HeadState, *, model_axis,
+                telemetry=None) -> HeadState:
         """Paper §3.2.2: suspend training, ring-build the exact KNN graph of
         the CURRENT class weights, compress per shard (host round-trip for
         CSR packing — an offline step in the paper)."""
         import numpy as np
+        tr = telemetry or NULL_TRACER
         n_dev = mesh.shape[model_axis]
-        graph = kg.build_graph_distributed(
-            mesh, head_state.params, k=self.head_cfg.knn_k,
-            kprime=self.head_cfg.knn_kprime, model_axis=model_axis,
-            backend=self.backend)
-        cg = kg.compress_graph(np.asarray(jax.device_get(graph)), n_dev)
-        sh = NamedSharding(mesh, P(model_axis, None))
-        aux = tuple(jax.device_put(a, sh)
-                    for a in (cg.offsets, cg.neighbors, cg.ranks))
+        with tr.span("train.refresh.build"):
+            graph = kg.build_graph_distributed(
+                mesh, head_state.params, k=self.head_cfg.knn_k,
+                kprime=self.head_cfg.knn_kprime, model_axis=model_axis,
+                backend=self.backend)
+        with tr.span("train.refresh.fetch"):
+            graph = np.asarray(jax.device_get(graph))
+        with tr.span("train.refresh.pack"):
+            cg = kg.compress_graph(graph, n_dev)
+        with tr.span("train.refresh.place"):
+            sh = NamedSharding(mesh, P(model_axis, None))
+            aux = tuple(jax.device_put(a, sh)
+                        for a in (cg.offsets, cg.neighbors, cg.ranks))
         return HeadState(params=head_state.params, aux=aux)
 
     def loss_local(self, f_all, y_all, params, aux, *, model_axis,
@@ -353,8 +362,8 @@ class SelectiveSoftmaxHead(FullSoftmaxHead):
     def refresh_every(self) -> int:
         return self.head_cfg.rebuild_every
 
-    def refresh(self, mesh, head_state: HeadState, *,
-                model_axis) -> HeadState:
+    def refresh(self, mesh, head_state: HeadState, *, model_axis,
+                telemetry=None) -> HeadState:
         n_dev = mesh.shape[model_axis]
         w = jax.device_get(head_state.params)
         planes, offsets, classes = self._build_tables(

@@ -169,9 +169,10 @@ def ring_knn_local(w_loc, *, k: int, kprime: int, model_axis: str, n_shards: int
     def _vary(x):  # mark as device-varying along the ring axis (scan carry)
         return jax.lax.pcast(x, (model_axis,), to="varying")
 
-    bv0 = _vary(jnp.full((n_loc, kprime), -jnp.inf, jnp.float32))
-    bi0 = _vary(jnp.full((n_loc, kprime), -1, jnp.int32))
-    _, bv, bi = jax.lax.fori_loop(0, n_shards, hop, (w16, bv0, bi0))
+    with jax.named_scope("knn_pass1"):
+        bv0 = _vary(jnp.full((n_loc, kprime), -jnp.inf, jnp.float32))
+        bi0 = _vary(jnp.full((n_loc, kprime), -1, jnp.int32))
+        _, bv, bi = jax.lax.fori_loop(0, n_shards, hop, (w16, bv0, bi0))
 
     # ---- pass 2: fp32 re-rank of the k' candidates ----------------------
     def hop32(step, carry):
@@ -190,10 +191,11 @@ def ring_knn_local(w_loc, *, k: int, kprime: int, model_axis: str, n_shards: int
         block = jax.lax.ppermute(block, model_axis, perm)
         return block, acc
 
-    acc0 = _vary(jnp.full((n_loc, kprime), -jnp.inf, jnp.float32))
-    _, exact = jax.lax.fori_loop(0, n_shards, hop32, (wn, acc0))
-    exact = jnp.where(bi >= 0, exact, -jnp.inf)
-    _, pos = jax.lax.top_k(exact, k)
+    with jax.named_scope("knn_pass2"):
+        acc0 = _vary(jnp.full((n_loc, kprime), -jnp.inf, jnp.float32))
+        _, exact = jax.lax.fori_loop(0, n_shards, hop32, (wn, acc0))
+        exact = jnp.where(bi >= 0, exact, -jnp.inf)
+        _, pos = jax.lax.top_k(exact, k)
     return jnp.take_along_axis(bi, pos, axis=1)
 
 

@@ -29,35 +29,49 @@ def split_microbatches(inputs: dict, n_micro: int) -> dict:
     return jax.tree.map(split, inputs)
 
 
+def _value_and_grad(loss_fn: Callable, params, inputs):
+    """``jax.value_and_grad(loss_fn, has_aux=True)(params, inputs)`` as its
+    vjp, so the forward and the backward each run under a name of their
+    own (``head_fwd``, ``head_bwd``) in the HLO and the device trace."""
+    with jax.named_scope("head_fwd"):
+        loss, vjp_fn, metrics = jax.vjp(lambda p: loss_fn(p, inputs), params,
+                                        has_aux=True)
+    with jax.named_scope("head_bwd"):
+        grads, = vjp_fn(jnp.ones_like(loss))
+    return (loss, metrics), grads
+
+
 def microbatched_value_and_grad(
     loss_fn: Callable, params, inputs: dict, n_micro: int,
 ):
     """Mean loss/grads over n_micro micro-batches via lax.scan.
 
     loss_fn(params, micro_inputs) -> (loss, metrics). Gradients accumulate in
-    fp32. Metrics are averaged. This is the pipelined/accumulated step body:
-    with n_micro=1 it degenerates to the paper's Fig. 4(a) baseline.
+    fp32 (under the name ``grad_accum``). Metrics are averaged. This is the
+    pipelined/accumulated step body: with n_micro=1 it degenerates to the
+    paper's Fig. 4(a) baseline.
     """
     if n_micro == 1:
-        (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params, inputs)
-        return (loss, metrics), grads
+        return _value_and_grad(loss_fn, params, inputs)
 
     micro = split_microbatches(inputs, n_micro)
-    gfn = jax.value_and_grad(loss_fn, has_aux=True)
 
     def body(carry, micro_inputs):
         acc_g, acc_l, acc_m = carry
-        (loss, metrics), grads = gfn(params, micro_inputs)
-        acc_g = jax.tree.map(
-            lambda a, g: a + g.astype(jnp.float32) / n_micro, acc_g, grads)
-        acc_m = jax.tree.map(lambda a, m: a + m / n_micro, acc_m, metrics)
+        (loss, metrics), grads = _value_and_grad(loss_fn, params,
+                                                 micro_inputs)
+        with jax.named_scope("grad_accum"):
+            acc_g = jax.tree.map(
+                lambda a, g: a + g.astype(jnp.float32) / n_micro, acc_g,
+                grads)
+            acc_m = jax.tree.map(lambda a, m: a + m / n_micro, acc_m,
+                                 metrics)
         return (acc_g, acc_l + loss / n_micro, acc_m), None
 
     g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
     first = jax.tree.map(lambda x: x[0], micro)
     m0 = jax.tree.map(lambda _: jnp.zeros((), jnp.float32),
-                      jax.eval_shape(lambda: gfn(params, first)[0][1]))
+                      jax.eval_shape(lambda: loss_fn(params, first)[1]))
     (grads, loss, metrics), _ = jax.lax.scan(
         body, (g0, jnp.zeros((), jnp.float32), m0), micro)
     return (loss, metrics), grads

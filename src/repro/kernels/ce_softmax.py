@@ -123,6 +123,7 @@ def ce_forward(f, w, y, *, limit=None, block_v: int = 512,
                         pltpu.VMEM((b,), jnp.float32),
                         pltpu.VMEM((b,), jnp.int32)],
         interpret=interpret,
+        name="ce_fwd",
     )(lim, f.astype(jnp.float32), w.astype(jnp.float32), y.astype(jnp.int32))
     return m, z, corr, amax
 
@@ -198,6 +199,7 @@ def ce_backward(f, w, y, m, gz, gc, *, limit=None,
                    pl.BlockSpec((b, d), lambda j: (0, 0))),
         scratch_shapes=[pltpu.VMEM((b, d), jnp.float32)],
         interpret=interpret,
+        name="ce_bwd",
     )(lim, f.astype(jnp.float32), w.astype(jnp.float32), y.astype(jnp.int32),
       m, gz.astype(jnp.float32), gc.astype(jnp.float32))
     return df, dw[:v]

@@ -100,6 +100,7 @@ def ivf_rerank(f, w, cand, k: int, *, block_a: int = 128, interpret: bool):
                         pltpu.VMEM((1, k), jnp.int32),
                         pltpu.VMEM((1, k), jnp.int32)],
         interpret=interpret,
+        name="ivf_rerank",
     )(safe, f.astype(jnp.float32).reshape(b, 1, d),
       w.astype(jnp.float32).reshape(v, 1, d), cand)
     return vals[:, 0], idx[:, 0]

@@ -153,6 +153,7 @@ def dist_topk(q: jax.Array, kmat: jax.Array, kprime: int, *,
         scratch_shapes=[pltpu.VMEM((block_q, kprime), jnp.float32),
                         pltpu.VMEM((block_q, kprime), jnp.int32)],
         interpret=interpret,
+        name="knn_dist_topk",
     )(q, kmat)
     vals, idx = vals[:nq], idx[:nq]
     real = (idx >= 0) & (idx < nk)

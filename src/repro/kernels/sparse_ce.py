@@ -199,6 +199,7 @@ def sparse_ce_forward(f, w, ids, gids, bias, valid, y, *, block_a: int = 128,
                         pltpu.VMEM((b,), jnp.int32),
                         pltpu.VMEM((b,), jnp.int32)],
         interpret=interpret,
+        name="sparse_ce_fwd",
     )(ids, f.astype(jnp.float32), w3, gids, bias, valid, y.astype(jnp.int32))
     return m, z, corr, amax
 
@@ -276,6 +277,7 @@ def sparse_ce_backward(f, w, ids, gids, bias, valid, y, m, gz, gc, *,
                         pltpu.VMEM((b, d), jnp.float32),
                         pltpu.VMEM((b,), jnp.int32)],
         interpret=interpret,
+        name="sparse_ce_bwd",
     )(ids, f.astype(jnp.float32), w3, gids, bias, valid, y.astype(jnp.int32),
       m, gz.astype(jnp.float32), gc.astype(jnp.float32))
     return df, dwa[:a]

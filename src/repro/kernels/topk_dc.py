@@ -52,5 +52,6 @@ def stage1_topk(chunks: jax.Array, k: int, *, block_rows: int = 8,
         out_specs=(pl.BlockSpec((block_rows, k), lambda i: (i, 0)),
                    pl.BlockSpec((block_rows, k), lambda i: (i, 0))),
         interpret=interpret,
+        name="topk_rows",
     )(chunks)
     return vals[:m], idx[:m]

@@ -140,38 +140,37 @@ class ServingEngine:
     def _run_batch(self, mb) -> List[Request]:
         tr = self.telemetry or NULL_TRACER
         n = len(mb.requests)
-        with tr.span("serve.flush"):
-            padded = self._pad([r.query for r in mb.requests], mb.bucket)
-        t0 = time.perf_counter_ns()
-        with warnings.catch_warnings():
-            # buffer donation is best-effort: XLA warns when out shapes
-            # cannot alias the donated input; that is expected here
-            warnings.filterwarnings("ignore", message=".*[Dd]onat.*")
-            ids, scores = self.step_fn(padded, n)
-        dt_ns = time.perf_counter_ns() - t0
-        dt = dt_ns * 1e-9
-        self.n_batches += 1
-        self.occupancies.append(mb.occupancy)
-        self.compute_s += dt
-        tr.add_span("serve.compute", t0, dt_ns)
-        tr.count("serve.batches")
-        tr.gauge("serve.occupancy", mb.occupancy)
-        if self.cache is not None:
-            tr.gauge("serve.cache_hit_rate", self.cache.hit_rate)
-        t_start = max(mb.t_flush, self._server_free_at)
-        t_done = t_start + dt
-        self._server_free_at = t_done
-        # queue wait on the engine clock: submit -> modeled batch start
-        tr.count("serve.queue_wait_s",
-                 sum(t_start - r.t_submit for r in mb.requests))
-        ids = np.asarray(ids)
-        scores = None if scores is None else np.asarray(scores)
-        for i, r in enumerate(mb.requests):
-            r.ids = ids[i].copy()
-            r.scores = None if scores is None else scores[i].copy()
-            r.t_start, r.t_done = t_start, t_done
-            if self.cache is not None:
-                self.cache.put(r.query, (r.ids, r.scores))
+        with tr.span("serve.batch", {"batch": self.n_batches, "n": n,
+                                     "rids": [r.rid for r in mb.requests]}):
+            with tr.span("serve.flush"):
+                padded = self._pad([r.query for r in mb.requests],
+                                   mb.bucket)
+            t0 = time.perf_counter_ns()
+            with tr.span("serve.compute"), warnings.catch_warnings():
+                # buffer donation is best-effort: XLA warns when out shapes
+                # cannot alias the donated input; that is expected here
+                warnings.filterwarnings("ignore", message=".*[Dd]onat.*")
+                ids, scores = self.step_fn(padded, n)
+            dt = (time.perf_counter_ns() - t0) * 1e-9
+            self.n_batches += 1
+            self.occupancies.append(mb.occupancy)
+            self.compute_s += dt
+            tr.count("serve.batches")
+            with tr.span("serve.deliver"):
+                t_start = max(mb.t_flush, self._server_free_at)
+                t_done = t_start + dt
+                self._server_free_at = t_done
+                # queue wait on the engine clock: submit -> modeled start
+                tr.count("serve.queue_wait_s",
+                         sum(t_start - r.t_submit for r in mb.requests))
+                ids = np.asarray(ids)
+                scores = None if scores is None else np.asarray(scores)
+                for i, r in enumerate(mb.requests):
+                    r.ids = ids[i].copy()
+                    r.scores = None if scores is None else scores[i].copy()
+                    r.t_start, r.t_done = t_start, t_done
+                    if self.cache is not None:
+                        self.cache.put(r.query, (r.ids, r.scores))
         return list(mb.requests)
 
     def _deliver(self, batches) -> List[Request]:
@@ -184,14 +183,16 @@ class ServingEngine:
     def poll(self, now: Optional[float] = None) -> List[Request]:
         """Run micro-batches due at ``now`` (full buckets, expired
         deadlines); returns every request completed since the last call."""
-        now = self.clock() if now is None else now
-        return self._deliver(self.coalescer.ready(now))
+        with (self.telemetry or NULL_TRACER).span("serve.poll"):
+            now = self.clock() if now is None else now
+            return self._deliver(self.coalescer.ready(now))
 
     def drain(self, now: Optional[float] = None) -> List[Request]:
         """Flush the queue regardless of deadlines and return everything
         completed since the last poll (shutdown / end of replay)."""
-        now = self.clock() if now is None else now
-        return self._deliver(self.coalescer.flush(now))
+        with (self.telemetry or NULL_TRACER).span("serve.poll"):
+            now = self.clock() if now is None else now
+            return self._deliver(self.coalescer.flush(now))
 
     def warmup(self, example_query, buckets: Optional[Sequence[int]] = None):
         """Pre-compile the step for every padding bucket so the first real

@@ -3,9 +3,11 @@
 One seam for every layer's observability (docs/telemetry.md):
 
   * ``Tracer`` — nestable wall-clock spans (``with tr.span("train.step")``)
-    over a monotonic ``perf_counter_ns`` clock, typed counters/gauges,
-    device peak-memory watermarks, a JSONL metrics sink, and Chrome-trace
-    (Perfetto) JSON export.
+    over a monotonic ``perf_counter_ns`` clock, each also written into the
+    profiler's trace as a ``jax.profiler.TraceAnnotation``; JAX's compile
+    path as ``jax.*`` spans; typed counters/gauges, device peak-memory
+    watermarks, a JSONL metrics sink, and Chrome-trace (Perfetto) JSON
+    export.
   * ``NULL_TRACER`` — the disabled singleton: every hot-path call is a
     constant-time no-op that allocates nothing, so instrumented code pays
     ~nothing when telemetry is off.

@@ -12,6 +12,14 @@ Design constraints (docs/telemetry.md):
     without thread-local magic.
   * The clock is injectable (``clock_ns=``) so tests drive a fake clock
     and span timing is deterministic.
+  * A live span is also a ``jax.profiler.TraceAnnotation`` (its scalar
+    attributes as the event's stats), so under a profiler session each
+    span sits in the device trace's timeline, on the profiler's clock. The
+    tracer's own record stays on ``clock_ns``.
+  * JAX's compile path reaches every live tracer as retroactive spans
+    (``jax.trace``, ``jax.lower``, ``jax.compile``, ``jax.cache_load``)
+    and the counter ``jax.compiles``, through one ``jax.monitoring``
+    listener registered when the first tracer is made.
 
 Export formats:
   * ``chrome_trace()`` / ``write_chrome_trace(path)`` — the Chrome
@@ -24,7 +32,11 @@ from __future__ import annotations
 
 import json
 import time
+import weakref
 from typing import Any, Callable, NamedTuple, Optional
+
+import jax
+from jax.profiler import TraceAnnotation
 
 from repro.telemetry.metrics import MetricsSink
 
@@ -39,10 +51,14 @@ class SpanEvent(NamedTuple):
     attrs: Optional[dict]
 
 
-class _Span:
-    """Context manager recording one span into its tracer on exit."""
+_SCALARS = (bool, int, float, str)
 
-    __slots__ = ("_tr", "name", "attrs", "start_ns", "depth")
+
+class _Span:
+    """Context manager recording one span into its tracer on exit, and
+    writing it into the profiler's trace while it is open."""
+
+    __slots__ = ("_tr", "name", "attrs", "start_ns", "depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Optional[dict]):
         self._tr = tracer
@@ -50,6 +66,13 @@ class _Span:
         self.attrs = attrs
 
     def __enter__(self):
+        if self.attrs:
+            self._ann = TraceAnnotation(self.name, **{
+                k: v for k, v in self.attrs.items()
+                if isinstance(v, _SCALARS)})
+        else:
+            self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self.depth = len(self._tr._stack)
         self._tr._stack.append(self.name)
         self.start_ns = self._tr.clock_ns()
@@ -61,7 +84,35 @@ class _Span:
         self._tr.events.append(SpanEvent(
             self.name, self.start_ns, end_ns - self.start_ns, self.depth,
             self.attrs))
+        self._ann.__exit__(exc_type, exc, tb)
         return False
+
+
+# jax.monitoring duration events of the compile path -> span names
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax.cache_load",
+}
+_LIVE: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_listening = False
+
+
+def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
+    name = _COMPILE_EVENTS.get(event)
+    if name is None:
+        return
+    for tr in list(_LIVE):
+        tr._compile_span(name, duration_secs, kwargs.get("fun_name"))
+
+
+def _watch_compiles(tracer: "Tracer") -> None:
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+    _LIVE.add(tracer)
 
 
 class Tracer:
@@ -77,6 +128,7 @@ class Tracer:
         self.gauges: dict[str, Any] = {}
         self._stack: list[str] = []
         self.sink = MetricsSink(metrics_path) if metrics_path else None
+        _watch_compiles(self)
 
     # -- spans -------------------------------------------------------------
 
@@ -85,10 +137,20 @@ class Tracer:
 
     def add_span(self, name: str, start_ns: int, dur_ns: int,
                  attrs: Optional[dict] = None, depth: int = 0) -> None:
-        """Record an externally-timed interval (e.g. the serving engine's
-        own ``perf_counter_ns`` compute window) as a span."""
+        """Record an externally-timed interval (e.g. a score-cache lookup
+        the serving engine timed itself) as a span. It is the tracer's
+        only: a retroactive interval never reaches the profiler's trace."""
         self.events.append(
             SpanEvent(name, int(start_ns), int(dur_ns), depth, attrs))
+
+    def _compile_span(self, name: str, secs: float,
+                      fun: Optional[str]) -> None:
+        """One compile-path event of JAX, as a span ending now."""
+        dur_ns = int(secs * 1e9)
+        self.add_span(name, self.clock_ns() - dur_ns, dur_ns,
+                      {"fun": fun} if fun else None, depth=len(self._stack))
+        if name == "jax.compile":
+            self.count("jax.compiles")
 
     def span_stats(self, name: str) -> dict:
         """{"count", "total_s"} over every recorded span named ``name``."""

@@ -87,12 +87,13 @@ def init_state(key, model_cfg: ModelConfig, head_cfg: HeadConfig,
                        jnp.zeros((), jnp.int32))
 
 
-def refresh_head_state(head: SoftmaxHead, mesh,
-                       state: HybridState) -> HybridState:
+def refresh_head_state(head: SoftmaxHead, mesh, state: HybridState, *,
+                       telemetry=None) -> HybridState:
     """Run the head's periodic work (graph/table rebuild) on the current
-    params; no-op for heads without any."""
+    params; no-op for heads without any. ``telemetry`` (a ``Tracer``)
+    receives the head's ``train.refresh.*`` spans."""
     hs = head.refresh(mesh, HeadState(state.head_params, state.head_aux),
-                      model_axis=AXIS)
+                      model_axis=AXIS, telemetry=telemetry)
     return state._replace(head_params=hs.params, head_aux=hs.aux)
 
 
@@ -198,10 +199,11 @@ def make_train_step(model_cfg: ModelConfig, head_cfg: HeadConfig,
                 jnp.float32)
         # head gradient: LOCAL — never crosses devices (paper §3.1 step 6)
 
-        updates, opt_state = opt.update((g_fe, g_hp), opt_state,
-                                        (fe_params, head_params), lr)
-        fe_params, head_params = apply_updates((fe_params, head_params),
-                                               updates)
+        with jax.named_scope("opt_update"):
+            updates, opt_state = opt.update((g_fe, g_hp), opt_state,
+                                            (fe_params, head_params), lr)
+            fe_params, head_params = apply_updates((fe_params, head_params),
+                                                   updates)
         metrics = dict(metrics)
         metrics["comm_wire_bytes"] = info.get("wire_bytes", jnp.zeros((), jnp.float32))
         metrics["comm_dense_bytes"] = info["dense_bytes"]

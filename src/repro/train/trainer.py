@@ -100,7 +100,7 @@ class PaperTrainer:
         t0 = time.perf_counter()
         with tr.span("train.refresh"):
             self.state = hybrid.refresh_head_state(self.head, self.mesh,
-                                                   self.state)
+                                                   self.state, telemetry=tr)
         tr.count("train.refreshes")
         return time.perf_counter() - t0
 
@@ -224,46 +224,51 @@ class PaperTrainer:
         ``step_hook(t)`` fires before each step — the fault-injection seam
         (``repro.resilience.faults``); whatever it raises propagates after
         any due checkpoint of the previous step was already written."""
-        fcfg = self.train_cfg.fccs
-        refresh_every = self.head.refresh_every
         start = self._t
         tr = self.telemetry or NULL_TRACER
         with jax.set_mesh(self.mesh):
             for t in range(start, start + total_steps):
-                if step_hook is not None:
-                    step_hook(t)
-                lr = (self.lr_fn(t) if self.lr_fn is not None
-                      else fccs.learning_rate(t, fcfg))
-                n = (_pow2_quantize(fccs.accum_steps(t, fcfg, self.hw_batch))
-                     if use_fccs_batch else 1)
-                with tr.span("train.data"):
-                    inputs = self.data_fn(t, self.hw_batch * n)
-                    step = self._get_step(n)
-                with tr.span("train.step"):
-                    self.state, loss, metrics = step(self.state, inputs, lr)
-                    if tr.enabled:
-                        # async dispatch would end the span at launch time;
-                        # only a live tracer pays for the sync
-                        jax.block_until_ready(loss)
-                tr.count("train.steps")
-                self._t = t + 1
-                if refresh_every and (t + 1) % refresh_every == 0:
-                    self.refresh_head()
-                if self.ckpt_dir and self.ckpt_every and \
-                        (t + 1) % self.ckpt_every == 0:
-                    with tr.span("train.checkpoint"):
-                        self.save_checkpoint()
-                    tr.count("train.checkpoints")
-                row = {"step": t, "lr": lr, "batch": self.hw_batch * n,
-                       "loss": float(loss),
-                       "acc": float(metrics["accuracy"])}
-                self.history.append(row)
-                tr.log_metrics(row)
-                if self.log_every and t % self.log_every == 0:
-                    print(f"[train] step={t} lr={lr:.4f} B={row['batch']} "
-                          f"loss={row['loss']:.4f} acc={row['acc']:.3f}")
+                with tr.span("train.update", {"step": t}):
+                    self._update(t, tr, use_fccs_batch, step_hook)
         tr.record_peak_memory()
         return self.history
+
+    def _update(self, t, tr, use_fccs_batch, step_hook):
+        """One iteration of ``run``: the step, then whatever is due."""
+        fcfg = self.train_cfg.fccs
+        refresh_every = self.head.refresh_every
+        if step_hook is not None:
+            step_hook(t)
+        lr = (self.lr_fn(t) if self.lr_fn is not None
+              else fccs.learning_rate(t, fcfg))
+        n = (_pow2_quantize(fccs.accum_steps(t, fcfg, self.hw_batch))
+             if use_fccs_batch else 1)
+        with tr.span("train.data"):
+            inputs = self.data_fn(t, self.hw_batch * n)
+            step = self._get_step(n)
+        with tr.span("train.step"):
+            self.state, loss, metrics = step(self.state, inputs, lr)
+            if tr.enabled:
+                # async dispatch would end the span at launch time; only a
+                # live tracer pays for the sync
+                jax.block_until_ready(loss)
+        tr.count("train.steps")
+        self._t = t + 1
+        if refresh_every and (t + 1) % refresh_every == 0:
+            self.refresh_head()
+        if self.ckpt_dir and self.ckpt_every and \
+                (t + 1) % self.ckpt_every == 0:
+            with tr.span("train.checkpoint"):
+                self.save_checkpoint()
+            tr.count("train.checkpoints")
+        with tr.span("train.log"):
+            row = {"step": t, "lr": lr, "batch": self.hw_batch * n,
+                   "loss": float(loss), "acc": float(metrics["accuracy"])}
+            self.history.append(row)
+            tr.log_metrics(row)
+            if self.log_every and t % self.log_every == 0:
+                print(f"[train] step={t} lr={lr:.4f} B={row['batch']} "
+                      f"loss={row['loss']:.4f} acc={row['acc']:.3f}")
 
     def evaluate(self, eval_inputs) -> float:
         with jax.set_mesh(self.mesh):

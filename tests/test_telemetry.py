@@ -1,9 +1,13 @@
 """repro.telemetry: span nesting under a fake clock, the disabled
 tracer's zero-allocation guarantee, Chrome-trace round-trip, the analytic
-comm ledger vs the compiled step's HLO, and JSONL sink append semantics
-(ISSUE 9 / docs/telemetry.md)."""
+comm ledger vs the compiled step's HLO, JSONL sink append semantics, live
+spans in the profiler's trace, JAX's compile path as spans, and the spans
+of the trainer loop and the serving engine (docs/telemetry.md)."""
+import gc
+import glob
 import json
 
+import numpy as np
 import pytest
 
 from repro.telemetry import (NULL_TRACER, CommLedger, MetricsSink, Tracer,
@@ -207,3 +211,180 @@ def test_ledger_fe_param_terms():
     assert labels["fe_grad_exchange"] == 4000.0
     with pytest.raises(ValueError, match="divisible"):
         train_step_ledger(n_dev=4, rows=33, feat_dim=16, n_micro=2)
+
+
+# ---------------------------------------------------------------------------
+# spans in the profiler's trace, and JAX's compile path
+# ---------------------------------------------------------------------------
+
+
+def _host_events(trace_dir):
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    return [e for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_live_spans_reach_the_profiler_trace(tmp_path):
+    import jax
+
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("t.outer", {"step": 3, "kind": "x", "rids": [1, 2]}):
+            with tr.span("t.inner", {"n": 2}):
+                pass
+        tr.add_span("t.retro", 0, 10)
+    finally:
+        jax.profiler.stop_trace()
+    by = {e.name: e for e in _host_events(tmp_path)
+          if e.name.startswith("t.")}
+    # the retroactive span is the tracer's only
+    assert set(by) == {"t.outer", "t.inner"}
+    outer, inner = by["t.outer"], by["t.inner"]
+    # scalar attrs ride as the event's stats; the list stays with the tracer
+    assert dict(outer.stats) == {"step": 3, "kind": "x"}
+    assert dict(inner.stats) == {"n": 2}
+    assert outer.start_ns <= inner.start_ns
+    assert inner.start_ns + inner.duration_ns \
+        <= outer.start_ns + outer.duration_ns
+    assert tr.events[-2].attrs["rids"] == [1, 2]
+
+
+def test_compile_path_reaches_live_tracers_only():
+    import jax
+    import jax.numpy as jnp
+
+    from repro.telemetry import tracer as tracer_mod
+
+    tr = Tracer()
+    with tr.span("outer"):
+        jax.jit(lambda x: x * 3 + 1)(jnp.ones(7)).block_until_ready()
+    got = [e for e in tr.events if e.name.startswith("jax.")]
+    assert {"jax.trace", "jax.lower", "jax.compile"} <= {e.name
+                                                         for e in got}
+    assert tr.counters["jax.compiles"] == sum(e.name == "jax.compile"
+                                              for e in got) >= 1
+    outer = tr.events[-1]
+    for e in got:
+        # retroactive spans that end by the time the call returns, at the
+        # depth of the span they happened in
+        assert e.dur_ns >= 0 and e.depth == 1
+        assert e.start_ns + e.dur_ns <= outer.start_ns + outer.dur_ns
+    assert any(e.attrs and "lambda" in e.attrs["fun"] for e in got)
+    # the disabled tracer is never registered and records nothing; a
+    # dropped tracer leaves the live set
+    assert NULL_TRACER not in tracer_mod._LIVE
+    assert NULL_TRACER.events == ()
+    n = len(tracer_mod._LIVE)
+    del tr
+    gc.collect()
+    assert len(tracer_mod._LIVE) == n - 1
+
+
+# ---------------------------------------------------------------------------
+# trainer loop and serving engine
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def tiny_knn():
+    """A tiny paper experiment whose KNN head refreshes every 2 updates."""
+    from repro.api import Experiment
+    from repro.configs.base import HeadConfig
+    from repro.train import hybrid
+
+    return Experiment.from_config(
+        system="paper", classes=64, feat_dim=16, batch=16,
+        head=HeadConfig(softmax_impl="knn", rebuild_every=2, knn_k=4,
+                        knn_kprime=8, active_frac=0.5),
+        mesh=hybrid.make_hybrid_mesh(2), log_every=0)
+
+
+def _children(events, parent):
+    """Spans one level below ``parent`` that lie inside it, by start."""
+    end = parent.start_ns + parent.dur_ns
+    return sorted((e for e in events if e.depth == parent.depth + 1
+                   and e.name.startswith(("train.", "serve."))
+                   and parent.start_ns <= e.start_ns
+                   and e.start_ns + e.dur_ns <= end),
+                  key=lambda e: e.start_ns)
+
+
+def test_trainer_update_spans_hold_their_children_in_order(tiny_knn):
+    tr = Tracer()
+    tiny_knn.trainer.telemetry = tr
+    try:
+        t0 = tiny_knn.trainer._t
+        tiny_knn.fit(4, use_fccs_batch=False)
+    finally:
+        tiny_knn.trainer.telemetry = None
+    updates = [e for e in tr.events if e.name == "train.update"]
+    assert [e.attrs["step"] for e in updates] == list(range(t0, t0 + 4))
+    assert all(e.depth == 0 for e in updates)
+    for u in updates:
+        kids = [e.name for e in _children(tr.events, u)]
+        refresh = (u.attrs["step"] + 1) % 2 == 0
+        assert kids == ["train.data", "train.step"] + (
+            ["train.refresh"] if refresh else []) + ["train.log"]
+    refreshes = [e for e in tr.events if e.name == "train.refresh"]
+    assert len(refreshes) == 2
+    for r in refreshes:
+        assert [e.name for e in _children(tr.events, r)] == [
+            "train.refresh.build", "train.refresh.fetch",
+            "train.refresh.pack", "train.refresh.place"]
+
+
+def _echo_engine(calls, **kw):
+    """An engine whose step answers each query with its own first entry
+    (the request id it was made from), and records each batch's ids."""
+    from repro.serving import ServingEngine
+
+    def step(q, n):
+        calls.append([int(x) for x in q[:n, 0]])
+        ids = np.repeat(q[:, :1].astype(np.int64), 2, axis=1)
+        return ids, np.zeros((len(q), 2), np.float32)
+
+    return ServingEngine(step, top_k=2, max_batch=4, max_wait_ms=1.0,
+                         clock=lambda: 0.0, **kw)
+
+
+def test_serve_batch_spans_carry_their_request_ids():
+    calls = []
+    tr = Tracer()
+    eng = _echo_engine(calls, telemetry=tr)
+    done = []
+    for i in range(10):
+        eng.submit(np.full(3, i, np.float32), now=i * 4e-4)
+        done += eng.poll(now=i * 4e-4)
+    done += eng.drain(now=1.0)
+    assert sorted(r.rid for r in done) == list(range(10))
+    batches = [e for e in tr.events if e.name == "serve.batch"]
+    assert [e.attrs["rids"] for e in batches] == calls
+    assert [e.attrs["n"] for e in batches] == [len(c) for c in calls]
+    assert [e.attrs["batch"] for e in batches] == list(range(len(calls)))
+    for b in batches:
+        assert [e.name for e in _children(tr.events, b)] == [
+            "serve.flush", "serve.compute", "serve.deliver"]
+    polls = [e for e in tr.events if e.name == "serve.poll"]
+    assert len(polls) == 11
+    for b in batches:
+        assert any(p.start_ns <= b.start_ns and b.start_ns + b.dur_ns
+                   <= p.start_ns + p.dur_ns for p in polls)
+    assert not tr.gauges
+
+
+def test_null_tracer_allocates_no_span_in_trainer_and_engine(tiny_knn):
+    from repro.telemetry.tracer import _NullSpan
+
+    assert tiny_knn.trainer.telemetry is None
+    before = _NullSpan.instances
+    tiny_knn.fit(2, use_fccs_batch=False)        # one update refreshes
+    calls = []
+    eng = _echo_engine(calls)
+    for i in range(6):
+        eng.submit(np.full(3, i, np.float32), now=0.0)
+        eng.poll(now=0.0)
+    eng.drain(now=1.0)
+    assert calls and _NullSpan.instances == before == 1
