@@ -11,7 +11,9 @@ composes with any trainer and any mesh:
   * ``SoftmaxHead`` — the protocol. A head owns its trainable params AND its
     auxiliary (non-trainable) state as pytrees, provides the
     ``PartitionSpec``s that place both on a mesh, a shard_map-compatible
-    ``loss_local`` body, a distributed ``eval_logits_local`` prediction body,
+    loss in two halves (a per-update ``prepare_params`` of the params and
+    the ``loss_prepared`` body that takes its result; ``loss_local`` joins
+    them), a distributed ``eval_logits_local`` prediction body,
     its metrics spec, and an optional ``refresh`` for periodic work (KNN
     graph rebuilds, LSH table rebuilds).
   * ``HEAD_REGISTRY`` / ``register_head`` / ``make_head`` — the registry
@@ -103,11 +105,37 @@ class SoftmaxHead:
     # -- shard_map bodies -------------------------------------------------
     def loss_local(self, f_all, y_all, params, aux, *, model_axis,
                    batch_axes, global_batch: int, step=None):
-        """Distributed CE on one device's shard. ``f_all``/``y_all`` are the
+        """Distributed CE on one device's shard: ``loss_prepared`` on
+        ``prepare_params(params)``. ``f_all``/``y_all`` are the
         ring-gathered (global) batch; ``step`` is the replicated training-
         step scalar (for heads with per-step randomness; may be None).
-        Returns (loss, metrics)."""
+        Returns (loss, metrics). Heads implement the two halves, not this:
+        the hybrid trainer calls them apart."""
+        return self.loss_prepared(f_all, y_all, self.prepare_params(params),
+                                  aux, model_axis=model_axis,
+                                  batch_axes=batch_axes,
+                                  global_batch=global_batch, step=step)
+
+    def prepare_params(self, params):
+        """Per-update transform of the trainable params on one device's
+        shard: row-local, and fixed while the params are (a cosine head's
+        row normalization of W). The hybrid trainer runs it once per
+        update, outside the micro-batch loop, and hands the result to
+        ``loss_prepared``. Identity by default."""
+        return params
+
+    def loss_prepared(self, f_all, y_all, prepared, aux, *, model_axis,
+                      batch_axes, global_batch: int, step=None):
+        """The loss body, on ``prepare_params(params)``."""
         raise NotImplementedError
+
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        if "loss_local" in vars(cls):
+            raise TypeError(
+                f"{cls.__name__} overrides loss_local; implement "
+                "loss_prepared (and prepare_params) instead, which the "
+                "hybrid trainer calls apart")
 
     def eval_logits_local(self, f_all, params, aux, *, model_axis):
         """Deploy-style prediction (§4.5 retrieval equivalence). Returns
@@ -220,10 +248,17 @@ class FullSoftmaxHead(SoftmaxHead):
     def params_spec(self, model_axis):
         return P(model_axis, None)
 
-    def loss_local(self, f_all, y_all, params, aux, *, model_axis,
-                   batch_axes, global_batch, step=None):
+    def prepare_params(self, params):
+        # a cosine head scores against unit rows of W; W is fixed within an
+        # update, so its normalization (and that normalization's gradient)
+        # runs once per update instead of once per micro-batch
+        return (_normalize(params) if self.head_cfg.cosine_scale > 0
+                else params)
+
+    def loss_prepared(self, f_all, y_all, prepared, aux, *, model_axis,
+                      batch_axes, global_batch, step=None):
         return full_softmax_local(
-            f_all, y_all, params, model_axis=model_axis,
+            f_all, y_all, prepared, model_axis=model_axis,
             batch_axes=batch_axes, global_batch=global_batch,
             cosine_scale=self.head_cfg.cosine_scale, n_valid=self.n_valid,
             backend=self.backend, block_v=self.block_v)
@@ -297,13 +332,19 @@ class KNNSoftmaxHead(FullSoftmaxHead):
                         for a in (cg.offsets, cg.neighbors, cg.ranks))
         return HeadState(params=head_state.params, aux=aux)
 
-    def loss_local(self, f_all, y_all, params, aux, *, model_axis,
-                   batch_axes, global_batch, step=None):
+    def prepare_params(self, params):
+        # the pallas body scores against the whole normalized shard, once
+        # per update; the ref body normalizes only the rows it gathers
+        # (active_frac of the shard) and takes the raw shard
+        return _normalize(params) if self.backend == "pallas" else params
+
+    def loss_prepared(self, f_all, y_all, prepared, aux, *, model_axis,
+                      batch_axes, global_batch, step=None):
         offsets, neighbors, ranks = aux
-        v_loc = params.shape[0]
+        v_loc = prepared.shape[0]
         m_local = max(8, int(v_loc * self.head_cfg.active_frac))
         return knn_softmax_local(
-            f_all, y_all, params, offsets, neighbors, ranks,
+            f_all, y_all, prepared, offsets, neighbors, ranks,
             model_axis=model_axis, batch_axes=batch_axes,
             global_batch=global_batch, m_local=m_local,
             k_cap=self.head_cfg.knn_k,
@@ -373,8 +414,13 @@ class SelectiveSoftmaxHead(FullSoftmaxHead):
                jax.device_put(offsets, sh), jax.device_put(classes, sh))
         return HeadState(params=head_state.params, aux=aux)
 
-    def loss_local(self, f_all, y_all, params, aux, *, model_axis,
-                   batch_axes, global_batch, step=None):
+    def prepare_params(self, params):
+        # the LSH body normalizes W itself (its ref path only the gathered
+        # rows), so it takes the raw shard
+        return params
+
+    def loss_prepared(self, f_all, y_all, params, aux, *, model_axis,
+                      batch_axes, global_batch, step=None):
         planes, offsets, classes = aux
         v_loc = params.shape[0]
         m_local = max(8, int(v_loc * self.head_cfg.active_frac))
@@ -468,8 +514,8 @@ class MACHSoftmaxHead(SoftmaxHead):
                                seed=self._hash_seed)
         return jnp.asarray(rebucket_sketch(a, h_old, h_new, b_dst))
 
-    def loss_local(self, f_all, y_all, params, aux, *, model_axis,
-                   batch_axes, global_batch, step=None):
+    def loss_prepared(self, f_all, y_all, params, aux, *, model_axis,
+                      batch_axes, global_batch, step=None):
         (hashes,) = aux
         return bl.mach_softmax_local(
             f_all, y_all, params, hashes, model_axis=model_axis,
@@ -505,8 +551,12 @@ class SampledSoftmaxHead(FullSoftmaxHead):
     (label + drawn negatives), like knn's active-set accuracy — use the
     deploy-style eval for full-vocabulary top-1."""
 
-    def loss_local(self, f_all, y_all, params, aux, *, model_axis,
-                   batch_axes, global_batch, step=None):
+    def prepare_params(self, params):
+        # the sampled body normalizes W itself, so it takes the raw shard
+        return params
+
+    def loss_prepared(self, f_all, y_all, params, aux, *, model_axis,
+                      batch_axes, global_batch, step=None):
         return bl.sampled_softmax_local(
             f_all, y_all, params, model_axis=model_axis,
             batch_axes=batch_axes, global_batch=global_batch,

@@ -107,7 +107,10 @@ def knn_softmax_local(
     gather-then-softmax (w_loc[ids] -> [b, m_local] logits) with the fused
     active-class sparse-CE kernel (``ops.sparse_ce_stats``): the gather and
     the online softmax run in one streamed sweep and neither the gathered
-    weights nor the logit tensor reach HBM."""
+    weights nor the logit tensor reach HBM. On that backend w_loc holds
+    unit rows (the head normalizes the whole shard once per update,
+    ``KNNSoftmaxHead.prepare_params``); the ref body gathers raw rows and
+    normalizes only those."""
     offsets = offsets_loc.reshape(-1)
     neighbors = neighbors_loc.reshape(-1)
     ranks = ranks_loc.reshape(-1) if ranks_loc is not None else None
@@ -128,7 +131,7 @@ def knn_softmax_local(
 
     if backend == "pallas":
         f = _normalize(f_loc).astype(jnp.float32)
-        wn = _normalize(w_loc).astype(jnp.float32)  # rows; == gather-then-norm
+        wn = w_loc.astype(jnp.float32)  # unit rows; == gather-then-norm
         gids = v_start + ids
         bias = jnp.zeros((ids.shape[0],), jnp.float32)
         m, z, corr, amax = ops.sparse_ce_stats(

@@ -41,6 +41,27 @@ def _value_and_grad(loss_fn: Callable, params, inputs):
     return (loss, metrics), grads
 
 
+def prepare_once(prepare: Callable, params):
+    """Run ``prepare(params)`` once per update, ahead of the micro-batch
+    loop, for a transform of the params that is fixed while they are (a
+    cosine head's row normalization of W).
+
+    The loop then differentiates the loss against ``prepared`` and
+    accumulates that gradient; ``pull_back`` maps the accumulated gradient
+    to one against ``params`` in a single vjp: J^T (sum g_i) / n in place of
+    sum J^T g_i / n, the same gradient up to float reassociation. The two
+    halves run under the names ``head_prepare`` and ``head_prepare_bwd``.
+    Returns (prepared, pull_back)."""
+    with jax.named_scope("head_prepare"):
+        prepared, vjp_fn = jax.vjp(prepare, params)
+
+    def pull_back(grads):
+        with jax.named_scope("head_prepare_bwd"):
+            return vjp_fn(grads)[0]
+
+    return prepared, pull_back
+
+
 def microbatched_value_and_grad(
     loss_fn: Callable, params, inputs: dict, n_micro: int,
 ):

@@ -171,10 +171,12 @@ def full_softmax_local(
     from the device's model-axis index). n_valid > 0 masks padded vocab rows
     (Megatron-style padding) out of the partition function. ``backend``
     routes the [b, V_loc] scoring through XLA (ref) or the streaming fused-CE
-    kernel (pallas — the logit tensor never hits HBM)."""
+    kernel (pallas — the logit tensor never hits HBM). For a cosine head
+    (cosine_scale > 0) w_loc holds unit rows: the head normalizes W once
+    per update (``FullSoftmaxHead.prepare_params``), and the body
+    normalizes only the features."""
     if backend == "pallas":
-        f, w = ((_normalize(f_loc), _normalize(w_loc)) if cosine_scale > 0
-                else (f_loc, w_loc))
+        f = _normalize(f_loc) if cosine_scale > 0 else f_loc
         scale = cosine_scale if cosine_scale > 0 else 1.0
         v_loc = w_loc.shape[0]
         v_start = _flat_axis_index(model_axis) * v_loc
@@ -183,16 +185,15 @@ def full_softmax_local(
         y_local = jnp.where(owned, pos, -1)
         limit = _shard_limit(v_start, v_loc, n_valid)
         m, z, corr, amax = ops.ce_shard_stats(
-            f.astype(jnp.float32), w.astype(jnp.float32), y_local, limit,
-            scale, block_v)
+            f.astype(jnp.float32), w_loc.astype(jnp.float32), y_local,
+            limit, scale, block_v)
         pred_gid = jnp.where(amax >= 0, v_start + amax, -1)
         return _finish_ce_stats(m, z, corr, pred_gid, y_loc, owned,
                                 model_axis, tuple(batch_axes),
                                 1.0 / global_batch)
     dt = f_loc.dtype
-    f, w = ((_normalize(f_loc), _normalize(w_loc)) if cosine_scale > 0
-            else (f_loc, w_loc.astype(dt)))
-    logits = jnp.einsum("bd,vd->bv", f, w.astype(dt),
+    f = _normalize(f_loc) if cosine_scale > 0 else f_loc
+    logits = jnp.einsum("bd,vd->bv", f, w_loc.astype(dt),
                         preferred_element_type=jnp.float32)
     if cosine_scale > 0:
         logits = logits * cosine_scale

@@ -36,7 +36,7 @@ from jax.sharding import PartitionSpec as P
 from repro.api.heads import HeadState, SoftmaxHead, make_head
 from repro.configs.base import HeadConfig, ModelConfig, TrainConfig
 from repro.core import sparsify as sp
-from repro.core.pipeline import microbatched_value_and_grad
+from repro.core.pipeline import microbatched_value_and_grad, prepare_once
 from repro.models import lm
 from repro.optim import apply_updates, make_optimizer
 
@@ -171,14 +171,18 @@ def make_train_step(model_cfg: ModelConfig, head_cfg: HeadConfig,
             # hybrid parallel: gather every replica's features along the ring
             f_all = jax.lax.all_gather(f, AXIS, axis=0, tiled=True)
             y_all = jax.lax.all_gather(y, AXIS, axis=0, tiled=True)
-            loss, metrics = head.loss_local(
+            loss, metrics = head.loss_prepared(
                 f_all, y_all, hp, head_aux, model_axis=AXIS, batch_axes=(),
                 global_batch=f_all.shape[0], step=step_no)
             return loss + aux, metrics
 
-        (loss, metrics), grads = microbatched_value_and_grad(
-            loss_fn, (fe_params, head_params), inputs_loc, n_micro)
-        g_fe, g_hp = grads
+        # the head's per-update transform runs on the local shard, once,
+        # outside the micro-batch loop (row-local: no collective)
+        hp_prepared, pull_back = prepare_once(head.prepare_params,
+                                              head_params)
+        (loss, metrics), (g_fe, g_prepared) = microbatched_value_and_grad(
+            loss_fn, (fe_params, hp_prepared), inputs_loc, n_micro)
+        g_hp = pull_back(g_prepared)
 
         info = {"wire_bytes": jnp.zeros((), jnp.float32),
                 "dense_bytes": jnp.zeros((), jnp.float32)}
@@ -245,6 +249,12 @@ def make_train_step(model_cfg: ModelConfig, head_cfg: HeadConfig,
                 loss, metrics)
 
     return step
+
+
+def head_prepare_passes(head: SoftmaxHead, head_params) -> int:
+    """Whole-shard passes of the head's per-update transform that one train
+    step makes: 1 where ``prepare_params`` does work, 0 for the identity."""
+    return int(bool(jax.make_jaxpr(head.prepare_params)(head_params).eqns))
 
 
 def _input_structure(model_cfg: ModelConfig):

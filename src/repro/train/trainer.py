@@ -82,6 +82,8 @@ class PaperTrainer:
         # tables) build it from the freshly-initialized weights; a no-op
         # for heads without periodic work.
         self.refresh_head()
+        self.head_prepare_passes = hybrid.head_prepare_passes(
+            self.head, self.state.head_params)
         self.eval_step = hybrid.make_eval_step(
             self.model_cfg, self.head_cfg, self.mesh, self.state,
             head=self.head)
@@ -226,6 +228,7 @@ class PaperTrainer:
         any due checkpoint of the previous step was already written."""
         start = self._t
         tr = self.telemetry or NULL_TRACER
+        tr.gauge("train.head_prepare_passes", self.head_prepare_passes)
         with jax.set_mesh(self.mesh):
             for t in range(start, start + total_steps):
                 with tr.span("train.update", {"step": t}):

@@ -4,15 +4,17 @@ comparison as a parametrized test), and the full/knn heads match their
 single-device oracles exactly."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.api import Experiment, HEAD_REGISTRY, make_head
-from repro.api.heads import HeadState
+from repro.api.heads import FullSoftmaxHead, HeadState
 from repro.configs.base import HeadConfig, ModelConfig, TrainConfig
 from repro.core import knn_graph as kg
 from repro.core import knn_softmax as ks
 from repro.core.sharded_softmax import ce_ref
 from repro.data.synthetic import ClassificationStream, sku_feature_batch
+from repro.telemetry import Tracer
 from repro.train import hybrid
 
 IMPLS = ["full", "knn", "selective", "mach", "sampled", "csoft"]
@@ -308,3 +310,201 @@ def test_paper_experiment_facade(mesh8):
     preds = exp.serve(batch=B)
     assert preds.shape == (B,)
     assert preds.dtype == jnp.int32
+
+
+# ---------------------------------------------------------------------------
+# the head's per-update transform, once per update (prepare_params)
+# ---------------------------------------------------------------------------
+
+
+class _PerMicroBatch:
+    """The head with its whole ``loss_local`` inside every micro-batch: the
+    identity transform, and the untransformed body. Everything else is the
+    wrapped head's."""
+
+    def __init__(self, head):
+        self._head = head
+
+    def __getattr__(self, name):
+        return getattr(self._head, name)
+
+    def prepare_params(self, params):
+        return params
+
+    def loss_prepared(self, *args, **kwargs):
+        return self._head.loss_local(*args, **kwargs)
+
+
+PREP_N, PREP_B = 512, 64
+
+
+def _prepared_problem(mesh, impl, backend, cosine_scale):
+    mcfg = _model_cfg(PREP_N)
+    hcfg = _head_cfg(impl, backend=backend, cosine_scale=cosine_scale,
+                     knn_k=8, knn_kprime=16, active_frac=0.2, sampled_n=128,
+                     mach_b=32, csoft_b=32)
+    tcfg = TrainConfig(optimizer="sgd", momentum=0.9)
+    head = make_head(mcfg, hcfg)
+    with jax.set_mesh(mesh):
+        state = hybrid.place_state(hybrid.init_state(
+            jax.random.PRNGKey(5), mcfg, hcfg, tcfg, 8, head=head),
+            head, mesh)
+        state = hybrid.refresh_head_state(head, mesh, state)
+    batch = sku_feature_batch(0, PREP_B, ClassificationStream(PREP_N, D,
+                                                              seed=2))
+    return mcfg, hcfg, tcfg, head, state, batch
+
+
+def _step_both_ways(mesh, impl, backend, cosine_scale, n_micro):
+    """One update with the transform out of the loop, and one with the
+    per-micro-batch form, from the same state: (loss, accuracy, head
+    gradient as the optimizer got it) for each."""
+    mcfg, hcfg, tcfg, head, state, batch = _prepared_problem(
+        mesh, impl, backend, cosine_scale)
+    out = []
+    for h in (head, _PerMicroBatch(head)):
+        step = hybrid.make_train_step(mcfg, hcfg, tcfg, mesh,
+                                      n_micro=n_micro, head=h,
+                                      state_template=state)
+        with jax.set_mesh(mesh):
+            new, loss, metrics = step(state, batch, 0.5)
+            out.append((float(loss), float(metrics["accuracy"]),
+                        np.asarray(jax.device_get(new.opt_state.mu[1]))))
+    return out
+
+
+@pytest.mark.parametrize("cosine_scale", [16.0, 0.0])
+@pytest.mark.parametrize("n_micro", [1, 4])
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+@pytest.mark.parametrize("impl", ["full", "knn"])
+def test_prepare_out_of_loop_matches_per_micro_batch(mesh8, impl, backend,
+                                                     n_micro, cosine_scale):
+    """The class matrix normalized once per update gives the loss, accuracy
+    and head gradient of normalizing it in every micro-batch, up to float
+    reassociation of the normalization's gradient."""
+    (l_a, acc_a, g_a), (l_b, acc_b, g_b) = _step_both_ways(
+        mesh8, impl, backend, cosine_scale, n_micro)
+    assert abs(l_a - l_b) <= 1e-6 * abs(l_b)
+    assert acc_a == acc_b
+    scale = np.max(np.abs(g_b))
+    np.testing.assert_allclose(g_a, g_b, rtol=0, atol=1e-5 * scale)
+
+
+@pytest.mark.parametrize("impl", ["selective", "sampled", "mach", "csoft"])
+def test_other_heads_keep_the_identity_transform(mesh8, impl):
+    """Heads whose bodies normalize what they gather keep the identity
+    transform, inherited or not: the step's numbers are exactly those of
+    ``loss_local`` in every micro-batch."""
+    (l_a, acc_a, g_a), (l_b, acc_b, g_b) = _step_both_ways(
+        mesh8, impl, "ref", 16.0, 4)
+    head = make_head(_model_cfg(PREP_N), _head_cfg(impl))
+    assert hybrid.head_prepare_passes(
+        head, jnp.zeros((PREP_N, D), jnp.float32)) == 0
+    assert (l_a, acc_a) == (l_b, acc_b)
+    np.testing.assert_array_equal(g_a, g_b)
+
+
+def _subjaxprs(eqn):
+    for v in eqn.params.values():
+        for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+            if hasattr(sub, "eqns"):
+                yield sub
+            elif hasattr(getattr(sub, "jaxpr", None), "eqns"):
+                yield sub.jaxpr
+
+
+def _eqns(jaxpr, in_loop=False):
+    """Every equation of the jaxpr and of its sub-jaxprs (Pallas kernels
+    left out), with whether it lies inside a loop's body."""
+    for eqn in jaxpr.eqns:
+        yield eqn, in_loop
+        if eqn.primitive.name == "pallas_call":
+            continue
+        loop = in_loop or eqn.primitive.name in ("scan", "while")
+        for sub in _subjaxprs(eqn):
+            yield from _eqns(sub, loop)
+
+
+def _w_row_reduces(jaxpr, w_shape):
+    """(inside the loop, outside it) counts of row reductions of a
+    [V_loc, D] array: a norm of W's rows, or that norm's gradient."""
+    counts = [0, 0]
+    for eqn, in_loop in _eqns(jaxpr):
+        if (eqn.primitive.name == "reduce_sum"
+                and tuple(eqn.params["axes"]) == (1,)
+                and eqn.invars[0].aval.shape == w_shape):
+            counts[0 if in_loop else 1] += 1
+    return counts
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+def test_head_prepare_runs_outside_the_micro_batch_loop(mesh8, backend):
+    """In the step program of the full cosine head with 4 micro-batches,
+    the ``head_prepare`` / ``head_prepare_bwd`` ops lie outside the scan's
+    body and no row normalization of W is left inside it; the
+    per-micro-batch form has it inside (the check can see it)."""
+    mcfg, hcfg, tcfg, head, state, batch = _prepared_problem(
+        mesh8, "full", backend, 16.0)
+    w_shape = (PREP_N // 8, D)
+    found = {}
+    for name, h in (("out", head), ("in", _PerMicroBatch(head))):
+        step = hybrid.make_train_step(mcfg, hcfg, tcfg, mesh8, n_micro=4,
+                                      head=h, state_template=state)
+        with jax.set_mesh(mesh8):
+            jaxpr = jax.make_jaxpr(step)(state, batch, 0.5).jaxpr
+        scopes = {"head_prepare": [0, 0], "head_prepare_bwd": [0, 0]}
+        for eqn, in_loop in _eqns(jaxpr):
+            stack = str(eqn.source_info.name_stack).split("/")
+            for scope in scopes:
+                if scope in stack:
+                    scopes[scope][0 if in_loop else 1] += 1
+        found[name] = (scopes, _w_row_reduces(jaxpr, w_shape))
+    scopes, reduces = found["out"]
+    assert scopes["head_prepare"][0] == scopes["head_prepare_bwd"][0] == 0
+    assert scopes["head_prepare"][1] > 0 and scopes["head_prepare_bwd"][1] > 0
+    assert reduces[0] == 0 and reduces[1] >= 2   # the norm and its gradient
+    scopes, reduces = found["in"]
+    assert reduces[0] >= 2
+    assert scopes["head_prepare"][1] == scopes["head_prepare_bwd"][1] == 0
+
+
+@pytest.mark.parametrize("backend,passes", [("pallas", 1), ("ref", 0)])
+def test_knn_normalizes_the_whole_shard_on_pallas_only(mesh8, backend,
+                                                       passes):
+    """The knn pallas body scores against the whole normalized shard, so
+    its normalization moves out of the loop; the ref body normalizes only
+    the rows it gathers, so its step program holds no row reduction of the
+    whole shard, inside the loop or out."""
+    mcfg, hcfg, tcfg, head, state, batch = _prepared_problem(
+        mesh8, "knn", backend, 16.0)
+    assert hybrid.head_prepare_passes(head, state.head_params) == passes
+    step = hybrid.make_train_step(mcfg, hcfg, tcfg, mesh8, n_micro=4,
+                                  head=head, state_template=state)
+    with jax.set_mesh(mesh8):
+        jaxpr = jax.make_jaxpr(step)(state, batch, 0.5).jaxpr
+    in_loop, out_loop = _w_row_reduces(jaxpr, (PREP_N // 8, D))
+    assert in_loop == 0
+    assert (out_loop >= 2) if passes else (out_loop == 0)
+
+
+def test_head_overriding_loss_local_is_refused():
+    """A head implements ``loss_prepared``; one that overrides
+    ``loss_local`` would be bypassed by the hybrid trainer, so defining it
+    fails."""
+    with pytest.raises(TypeError, match="loss_prepared"):
+        class _Bypassed(FullSoftmaxHead):
+            def loss_local(self, *args, **kwargs):
+                raise AssertionError("never called")
+
+
+@pytest.mark.parametrize("cosine_scale,passes", [(16.0, 1), (0.0, 0)])
+def test_head_prepare_passes_gauge(mesh8, cosine_scale, passes):
+    """The trainer reports one whole-shard prepare pass per update for a
+    cosine head, none for a raw one."""
+    tr = Tracer()
+    exp = Experiment.from_config(
+        system="paper", classes=N, feat_dim=D, batch=B, mesh=mesh8,
+        head=_head_cfg("full", cosine_scale=cosine_scale), log_every=0,
+        telemetry=tr)
+    exp.fit(1, use_fccs_batch=False)
+    assert tr.gauges["train.head_prepare_passes"] == passes

@@ -46,8 +46,11 @@ def problem():
 def test_loss_matches_oracle(mesh2x4, problem, cosine):
     f, w, y = problem
     fn = _make(mesh2x4, f.shape[0], cosine)
+    # a cosine head hands the body unit rows of W (its prepare_params); the
+    # oracle normalizes the raw W itself
+    w_in = ss._normalize(w) if cosine else w
     with jax.set_mesh(mesh2x4):
-        loss, m = jax.jit(fn)(f, y, w)
+        loss, m = jax.jit(fn)(f, y, w_in)
     loss_ref, m_ref = ss.ce_ref(f, y, w, cosine_scale=cosine)
     assert abs(float(loss) - float(loss_ref)) < 1e-4
     assert abs(float(m["accuracy"]) - float(m_ref["accuracy"])) < 1e-6
