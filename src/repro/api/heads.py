@@ -44,7 +44,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import HeadConfig, ModelConfig, effective_vocab
 from repro.core import baselines as bl
 from repro.core import knn_graph as kg
-from repro.core.knn_softmax import knn_softmax_local
+from repro.core.knn_softmax import knn_softmax_local, live_slots
 from repro.core.sharded_softmax import (_normalize, full_softmax_local,
                                         serve_argmax_local,
                                         serve_logits_local)
@@ -127,6 +127,25 @@ class SoftmaxHead:
     def loss_prepared(self, f_all, y_all, prepared, aux, *, model_axis,
                       batch_axes, global_batch: int, step=None):
         """The loss body, on ``prepare_params(params)``."""
+        raise NotImplementedError
+
+    def grad_rows(self, v_loc: int, batch: int) -> int:
+        """Rows of a [v_loc, D] class-matrix shard that the loss's gradient
+        can write in one call on ``batch`` (ring-gathered) rows, for a head
+        whose class-matrix gradient is a set of rows (``loss_rows``); 0 for
+        a dense gradient (the default). The hybrid trainer then adds those
+        rows into its accumulator instead of a dense [v_loc, D] gradient per
+        micro-batch."""
+        return 0
+
+    def loss_rows(self, f_all, y_all, prepared, rows, aux, *, model_axis,
+                  batch_axes, global_batch: int, step=None):
+        """``loss_prepared`` for a head with ``grad_rows``: ``rows`` is a
+        zero [grad_rows, D] operand added to the rows of ``prepared`` the
+        loss reads, so the class matrix's gradient arrives as ``rows``'
+        cotangent and ``prepared`` gets none. Returns (loss, metrics,
+        ids): ids [grad_rows] the local rows ``rows`` stands for, an id
+        outside [0, v_loc) for a slot that writes nothing."""
         raise NotImplementedError
 
     def __init_subclass__(cls, **kwargs):
@@ -338,19 +357,35 @@ class KNNSoftmaxHead(FullSoftmaxHead):
         # (active_frac of the shard) and takes the raw shard
         return _normalize(params) if self.backend == "pallas" else params
 
+    def _m_local(self, v_loc: int) -> int:
+        return max(8, int(v_loc * self.head_cfg.active_frac))
+
+    def grad_rows(self, v_loc: int, batch: int) -> int:
+        # the pallas body's gradient is the kernel's compact rows, one per
+        # slot that can be live; the ref body's is its gather's, dense
+        if self.backend != "pallas":
+            return 0
+        return live_slots(self._m_local(v_loc), batch, self.head_cfg.knn_k,
+                          self.head_cfg.knn_pad_random)
+
     def loss_prepared(self, f_all, y_all, prepared, aux, *, model_axis,
                       batch_axes, global_batch, step=None):
+        return self.loss_rows(f_all, y_all, prepared, None, aux,
+                              model_axis=model_axis, batch_axes=batch_axes,
+                              global_batch=global_batch, step=step)
+
+    def loss_rows(self, f_all, y_all, prepared, rows, aux, *, model_axis,
+                  batch_axes, global_batch, step=None):
         offsets, neighbors, ranks = aux
-        v_loc = prepared.shape[0]
-        m_local = max(8, int(v_loc * self.head_cfg.active_frac))
         return knn_softmax_local(
             f_all, y_all, prepared, offsets, neighbors, ranks,
             model_axis=model_axis, batch_axes=batch_axes,
-            global_batch=global_batch, m_local=m_local,
+            global_batch=global_batch,
+            m_local=self._m_local(prepared.shape[0]),
             k_cap=self.head_cfg.knn_k,
             cosine_scale=self.head_cfg.cosine_scale,
             pad_random=self.head_cfg.knn_pad_random, n_valid=self.n_valid,
-            backend=self.backend, block_a=self.block_a)
+            backend=self.backend, block_a=self.block_a, rows=rows)
 
     def metrics_spec(self) -> dict:
         return {"accuracy": P(), "logz": P(), "active_frac": P(),

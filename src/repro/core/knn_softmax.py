@@ -93,24 +93,38 @@ def select_active(
     return ids.astype(jnp.int32), mask
 
 
+def live_slots(m_local: int, b: int, k_cap: int, pad_random: bool) -> int:
+    """How many of ``select_active``'s slots can hold an active class, for
+    b labels: every slot with filler classes; without them at most one per
+    candidate (b * k_cap), and those come first (the rest are padding)."""
+    return m_local if pad_random else min(m_local, b * k_cap)
+
+
 def knn_softmax_local(
     f_loc, y_loc, w_loc, offsets_loc, neighbors_loc, ranks_loc=None, *,
     model_axis: str, batch_axes: Sequence[str], global_batch: int,
     m_local: int, k_cap: int, cosine_scale: float = 16.0,
     pad_random: bool = True, n_valid: int = 0, backend: str = "ref",
-    block_a: int = 128,
+    block_a: int = 128, rows=None,
 ):
     """shard_map body for the KNN-softmax loss (counterpart of
     full_softmax_local). offsets_loc [1, N+1] / neighbors_loc / ranks_loc
     [1, nnz] arrive with the leading model-shard axis from the sharded
     CompressedGraph. ``backend="pallas"`` replaces the dense
     gather-then-softmax (w_loc[ids] -> [b, m_local] logits) with the fused
-    active-class sparse-CE kernel (``ops.sparse_ce_stats``): the gather and
+    active-class sparse-CE kernel (``ops.sparse_ce_rows``): the gather and
     the online softmax run in one streamed sweep and neither the gathered
     weights nor the logit tensor reach HBM. On that backend w_loc holds
     unit rows (the head normalizes the whole shard once per update,
     ``KNNSoftmaxHead.prepare_params``); the ref body gathers raw rows and
-    normalizes only those."""
+    normalizes only those.
+
+    w_loc's gradient is the active rows' gradient scatter-added into the
+    shard. ``rows`` (pallas only) is a zero [n, D] operand for the first n
+    slots, n at least ``live_slots``, that takes it instead, compact, and
+    w_loc gets none: the body then returns (loss, metrics, ids), ids [n]
+    the local rows those slots read, v_loc for a masked slot (its gradient
+    row is zeros)."""
     offsets = offsets_loc.reshape(-1)
     neighbors = neighbors_loc.reshape(-1)
     ranks = ranks_loc.reshape(-1) if ranks_loc is not None else None
@@ -134,9 +148,20 @@ def knn_softmax_local(
         wn = w_loc.astype(jnp.float32)  # unit rows; == gather-then-norm
         gids = v_start + ids
         bias = jnp.zeros((ids.shape[0],), jnp.float32)
-        m, z, corr, amax = ops.sparse_ce_stats(
-            f, wn, ids, gids, bias, valid.astype(jnp.int32), y_loc,
-            cosine_scale, block_a, False)
+        if rows is None:
+            m, z, corr, amax = ops.sparse_ce_stats(
+                f, wn, ids, gids, bias, valid.astype(jnp.int32), y_loc,
+                cosine_scale, block_a, False)
+        else:
+            n = rows.shape[0]
+            if n < live_slots(m_local, y_loc.shape[0], k_cap, pad_random):
+                raise ValueError(f"{n} gradient rows miss live slots")
+            # the slots past the first n are never live: their gradient
+            # rows are zeros, and the step need not add them
+            m, z, corr, amax = ops.sparse_ce_rows(
+                f, wn, jnp.pad(rows, ((0, m_local - n), (0, 0))), ids, gids,
+                bias, valid.astype(jnp.int32), y_loc, cosine_scale, block_a,
+                False)
         corr = jnp.where(owned, corr, 0.0)
         pred_gid = jnp.where(amax >= 0, gids[jnp.maximum(amax, 0)], -1)
         loss, metrics = _finish_ce_stats(m, z, corr, pred_gid, y_loc, owned,
@@ -158,7 +183,9 @@ def knn_softmax_local(
     found = jax.lax.psum(owned.astype(jnp.float32), model_axis)  # [b] 0/1
     metrics["label_recall"] = jax.lax.psum(
         jnp.sum(found), tuple(batch_axes)) / global_batch
-    return loss, metrics
+    if rows is None:
+        return loss, metrics
+    return loss, metrics, jnp.where(valid, ids, v_loc)[:rows.shape[0]]
 
 
 def knn_softmax_ref(features, labels, w, graph, *, m: int,

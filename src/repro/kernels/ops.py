@@ -5,9 +5,11 @@ interpreter on every other backend (``use_interpreter``), so the CPU tests
 check the same kernel bodies the chip runs.
 
 The two CE entry points the heads consume are ``ce_shard_stats`` (dense
-vocab-shard sweep) and ``sparse_ce_stats`` (active-class gather + CE). Both
-are ``jax.custom_vjp`` over per-row ONLINE-SOFTMAX STATS (m, z, corr, amax)
-rather than over a scalar loss: the distributed completion (pmax/psum across
+vocab-shard sweep) and ``sparse_ce_rows`` (active-class gather + CE, whose
+class-matrix gradient is the compact rows; ``sparse_ce_stats`` is its form
+with that gradient scattered into the shard). Both are ``jax.custom_vjp``
+over per-row ONLINE-SOFTMAX STATS (m, z, corr, amax) rather than over a
+scalar loss: the distributed completion (pmax/psum across
 model shards, metrics) is plain jnp in ``core.sharded_softmax``, and its
 autodiff delivers the per-row cotangents (gz, gc) that the streaming
 backward kernels consume. The running max m is non-differentiable by
@@ -181,45 +183,64 @@ def fused_ce_stats(f, w, y, *, scale: float = 1.0, block_v: int = 512):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
-def sparse_ce_stats(f, w, ids, gids, bias, valid, y, scale: float = 1.0,
-                    block_a: int = 128, mask_hits: bool = False):
-    """Fused gather + streaming CE stats over an active-class set.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def sparse_ce_rows(f, w, rows, ids, gids, bias, valid, y, scale: float = 1.0,
+                   block_a: int = 128, mask_hits: bool = False):
+    """Fused gather + streaming CE stats over an active-class set, with the
+    class matrix's gradient given as rows.
 
     f [B,D]; w [V_loc,D] (full local shard — rows are gathered in-kernel);
+    rows [A,D] zeros that stand for the gathered rows: the stats are those
+    of ``w[ids] + rows``, and the gradient against the A rows the kernel
+    reads arrives as ``rows``' cotangent, compact. ``w`` gets none, so the
+    caller decides where those rows go (``sparse_ce_stats`` scatters them
+    into the shard; the hybrid step adds them into its accumulator).
     ids [A] local candidate rows; gids [A] global candidate ids; bias [A]
     per-column logit shift (-logQ for sampled, zeros otherwise); valid [A]
-    column mask; y [B] GLOBAL labels. ``mask_hits`` drops candidates whose
-    gid equals the row label from z (sampled accidental hits) instead of
-    folding them into corr (knn / selective label columns).
+    column mask (a masked column's gradient row is exact zeros); y [B]
+    GLOBAL labels. ``mask_hits`` drops candidates whose gid equals the row
+    label from z (sampled accidental hits) instead of folding them into
+    corr (knn / selective label columns).
 
-    Returns per-row fp32 (m, z, corr, amax-col); m / amax non-diff. Only f
-    and w receive gradients; dW is a compact [A, D] kernel output
-    scatter-added into the shard here."""
+    Returns per-row fp32 (m, z, corr, amax-col); m / amax non-diff. The
+    kernels read ``w`` only: ``rows`` must be zero."""
     return _sp.sparse_ce_forward(f, w, ids, gids, bias, valid, y,
                                  scale=scale, block_a=block_a,
                                  mask_hits=mask_hits,
                                  interpret=use_interpreter())
 
 
-def _sparse_ce_fwd(f, w, ids, gids, bias, valid, y, scale, block_a,
+def _sparse_ce_fwd(f, w, rows, ids, gids, bias, valid, y, scale, block_a,
                    mask_hits):
     m, z, corr, amax = _sp.sparse_ce_forward(
         f, w, ids, gids, bias, valid, y, scale=scale, block_a=block_a,
         mask_hits=mask_hits, interpret=use_interpreter())
-    return (m, z, corr, amax), (f, w, ids, gids, bias, valid, y, m)
+    return (m, z, corr, amax), (f, w, rows, ids, gids, bias, valid, y, m)
 
 
 def _sparse_ce_bwd(scale, block_a, mask_hits, res, cts):
-    f, w, ids, gids, bias, valid, y, m = res
+    f, w, rows, ids, gids, bias, valid, y, m = res
     _, gz, gc, _ = cts          # gm / gamax ignored: exact (see module doc)
     df, dwa = _sp.sparse_ce_backward(
         f, w, ids, gids, bias, valid, y, m, gz, gc, scale=scale,
         block_a=block_a, mask_hits=mask_hits, interpret=use_interpreter())
+    return (df.astype(f.dtype), None, dwa.astype(rows.dtype), None, None,
+            None, None, None)
+
+
+sparse_ce_rows.defvjp(_sparse_ce_fwd, _sparse_ce_bwd)
+
+
+def sparse_ce_stats(f, w, ids, gids, bias, valid, y, scale: float = 1.0,
+                    block_a: int = 128, mask_hits: bool = False):
+    """``sparse_ce_rows`` with the class matrix's gradient dense: the rows
+    stand for ``w[ids]`` (a zero that autodiff maps back through the
+    gather), so ``w`` receives the rows' gradient scatter-added into the
+    [V_loc, D] shard, a repeated id once per slot. The form for callers
+    that differentiate against the shard itself (selective, sampled, and
+    knn outside the hybrid step)."""
     safe = jnp.clip(ids.astype(jnp.int32), 0, w.shape[0] - 1)
-    dw = jnp.zeros(w.shape, jnp.float32).at[safe].add(dwa)
-    return (df.astype(f.dtype), dw.astype(w.dtype), None, None, None, None,
-            None)
-
-
-sparse_ce_stats.defvjp(_sparse_ce_fwd, _sparse_ce_bwd)
+    picked = w[safe]
+    rows = picked - jax.lax.stop_gradient(picked)
+    return sparse_ce_rows(f, w, rows, ids, gids, bias, valid, y, scale,
+                          block_a, mask_hits)

@@ -28,10 +28,12 @@ kernel fuses all three stages:
   backward — second sweep re-gathers + recomputes each tile's scores and
   applies per-row cotangents (gz, gc) exactly like ce_softmax's backward:
   dlogits = (exp(s - m) * gz + onehot * gc) * scale. dW comes out as the
-  compact per-tile [ba, D] product; the wrapper (ops.sparse_ce_stats)
-  scatter-adds it into the [V_local, D] shard.
+  compact per-tile [ba, D] product, one row per active slot; the wrapper
+  (ops.sparse_ce_rows) hands those rows back as they are, and its caller
+  adds them where they belong (ops.sparse_ce_stats scatters them into the
+  [V_local, D] shard; the hybrid step adds them into its accumulator).
 
-Wrapped by ``ops.sparse_ce_stats`` (jax.custom_vjp).
+Wrapped by ``ops.sparse_ce_rows`` (jax.custom_vjp).
 """
 from __future__ import annotations
 
@@ -252,7 +254,7 @@ def sparse_ce_backward(f, w, ids, gids, bias, valid, y, m, gz, gc, *,
                        block_a: int = 128, scale: float = 1.0,
                        mask_hits: bool = False, interpret: bool):
     """Streamed backward. Returns (df [B,D], dw_act [A,D] per-candidate
-    weight grads — scatter-add into [V_loc, D] is the wrapper's job)."""
+    weight grads — where they are added is the caller's choice)."""
     b, d = f.shape
     a = ids.shape[0]
     ba = min(block_a, max(8, a))

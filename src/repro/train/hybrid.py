@@ -36,7 +36,8 @@ from jax.sharding import PartitionSpec as P
 from repro.api.heads import HeadState, SoftmaxHead, make_head
 from repro.configs.base import HeadConfig, ModelConfig, TrainConfig
 from repro.core import sparsify as sp
-from repro.core.pipeline import microbatched_value_and_grad, prepare_once
+from repro.core.pipeline import (RowTap, microbatched_value_and_grad,
+                                 prepare_once)
 from repro.models import lm
 from repro.optim import apply_updates, make_optimizer
 
@@ -165,23 +166,39 @@ def make_train_step(model_cfg: ModelConfig, head_cfg: HeadConfig,
 
     def body(fe_params, head_params, head_aux, opt_state, dgc_u, dgc_v,
              inputs_loc, lr, step_no):
+        # the head's per-update transform runs on the local shard, once,
+        # outside the micro-batch loop (row-local: no collective)
+        hp_prepared, pull_back = prepare_once(head.prepare_params,
+                                              head_params)
+
         def loss_fn(params, micro_inputs):
             fe_p, hp = params
             f, y, aux = _flat_features_and_labels(model_cfg, fe_p, micro_inputs)
             # hybrid parallel: gather every replica's features along the ring
             f_all = jax.lax.all_gather(f, AXIS, axis=0, tiled=True)
             y_all = jax.lax.all_gather(y, AXIS, axis=0, tiled=True)
-            loss, metrics = head.loss_prepared(
-                f_all, y_all, hp, head_aux, model_axis=AXIS, batch_axes=(),
-                global_batch=f_all.shape[0], step=step_no)
+            kw = dict(model_axis=AXIS, batch_axes=(),
+                      global_batch=f_all.shape[0], step=step_no)
+            if isinstance(hp, RowTap):
+                loss, metrics, ids = head.loss_rows(
+                    f_all, y_all, hp_prepared, hp.rows, head_aux, **kw)
+                return loss + aux, (metrics, (ids,))
+            loss, metrics = head.loss_prepared(f_all, y_all, hp, head_aux,
+                                               **kw)
             return loss + aux, metrics
 
-        # the head's per-update transform runs on the local shard, once,
-        # outside the micro-batch loop (row-local: no collective)
-        hp_prepared, pull_back = prepare_once(head.prepare_params,
-                                              head_params)
+        # a head whose class-matrix gradient is a set of rows is
+        # differentiated against those rows, which the loop adds into its
+        # accumulator; any other against the prepared shard
+        micro_rows = inputs_loc["labels"].size // n_micro * n_dev
+        grad_rows = head.grad_rows(hp_prepared.shape[0], micro_rows)
+        if grad_rows:
+            hp = RowTap(jnp.zeros((grad_rows,) + hp_prepared.shape[1:],
+                                  hp_prepared.dtype), hp_prepared.shape[0])
+        else:
+            hp = hp_prepared
         (loss, metrics), (g_fe, g_prepared) = microbatched_value_and_grad(
-            loss_fn, (fe_params, hp_prepared), inputs_loc, n_micro)
+            loss_fn, (fe_params, hp), inputs_loc, n_micro)
         g_hp = pull_back(g_prepared)
 
         info = {"wire_bytes": jnp.zeros((), jnp.float32),

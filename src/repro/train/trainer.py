@@ -84,6 +84,11 @@ class PaperTrainer:
         self.refresh_head()
         self.head_prepare_passes = hybrid.head_prepare_passes(
             self.head, self.state.head_params)
+        # class-matrix rows the head's gradient writes per micro-batch: the
+        # head's row-sparse declaration, else the whole shard
+        v_loc = self.model_cfg.vocab_size // n_dev
+        self.head_grad_rows = (self.head.grad_rows(v_loc, self.hw_batch)
+                               or v_loc)
         self.eval_step = hybrid.make_eval_step(
             self.model_cfg, self.head_cfg, self.mesh, self.state,
             head=self.head)
@@ -229,6 +234,7 @@ class PaperTrainer:
         start = self._t
         tr = self.telemetry or NULL_TRACER
         tr.gauge("train.head_prepare_passes", self.head_prepare_passes)
+        tr.gauge("train.head_grad_rows", self.head_grad_rows)
         with jax.set_mesh(self.mesh):
             for t in range(start, start + total_steps):
                 with tr.span("train.update", {"step": t}):

@@ -319,8 +319,8 @@ def test_paper_experiment_facade(mesh8):
 
 class _PerMicroBatch:
     """The head with its whole ``loss_local`` inside every micro-batch: the
-    identity transform, and the untransformed body. Everything else is the
-    wrapped head's."""
+    identity transform, the untransformed body, and a dense class-matrix
+    gradient. Everything else is the wrapped head's."""
 
     def __init__(self, head):
         self._head = head
@@ -331,6 +331,9 @@ class _PerMicroBatch:
     def prepare_params(self, params):
         return params
 
+    def grad_rows(self, v_loc, batch):
+        return 0
+
     def loss_prepared(self, *args, **kwargs):
         return self._head.loss_local(*args, **kwargs)
 
@@ -338,11 +341,12 @@ class _PerMicroBatch:
 PREP_N, PREP_B = 512, 64
 
 
-def _prepared_problem(mesh, impl, backend, cosine_scale):
+def _prepared_problem(mesh, impl, backend, cosine_scale, **head_kw):
     mcfg = _model_cfg(PREP_N)
-    hcfg = _head_cfg(impl, backend=backend, cosine_scale=cosine_scale,
-                     knn_k=8, knn_kprime=16, active_frac=0.2, sampled_n=128,
-                     mach_b=32, csoft_b=32)
+    kw = dict(knn_k=8, knn_kprime=16, active_frac=0.2, sampled_n=128,
+              mach_b=32, csoft_b=32)
+    kw.update(head_kw)
+    hcfg = _head_cfg(impl, backend=backend, cosine_scale=cosine_scale, **kw)
     tcfg = TrainConfig(optimizer="sgd", momentum=0.9)
     head = make_head(mcfg, hcfg)
     with jax.set_mesh(mesh):
@@ -508,3 +512,161 @@ def test_head_prepare_passes_gauge(mesh8, cosine_scale, passes):
         telemetry=tr)
     exp.fit(1, use_fccs_batch=False)
     assert tr.gauges["train.head_prepare_passes"] == passes
+
+
+# ---------------------------------------------------------------------------
+# a row-sparse class-matrix gradient, added into the accumulator (grad_rows)
+# ---------------------------------------------------------------------------
+
+
+class _DenseRows:
+    """The head with its class-matrix gradient formed dense in every
+    micro-batch (``zeros.at[ids].add(dwa)``, the gather's transpose) and
+    then added to the accumulator. Everything else is the wrapped head's."""
+
+    def __init__(self, head):
+        self._head = head
+
+    def __getattr__(self, name):
+        return getattr(self._head, name)
+
+    def grad_rows(self, v_loc, batch):
+        return 0
+
+
+def _one_update(mesh, heads, problem, n_micro=4):
+    """(loss, metrics, gradient as the optimizer got it, class matrix after
+    the update) of one update from the same state, for each head."""
+    mcfg, hcfg, tcfg, _, state, batch = problem
+    out = []
+    for h in heads:
+        step = hybrid.make_train_step(mcfg, hcfg, tcfg, mesh,
+                                      n_micro=n_micro, head=h,
+                                      state_template=state)
+        with jax.set_mesh(mesh):
+            new, loss, metrics = step(state, batch, 0.5)
+            out.append((float(loss), jax.device_get(metrics),
+                        np.asarray(jax.device_get(new.opt_state.mu[1])),
+                        np.asarray(jax.device_get(new.head_params))))
+    return out
+
+
+@pytest.mark.parametrize("pad_random,active_frac,k,rows", [
+    (False, 1.0, 2, 32), (True, 0.5, 8, 32)])
+def test_knn_row_gradient_matches_the_dense_formula(mesh8, pad_random,
+                                                    active_frac, k, rows):
+    """The knn pallas step adds each micro-batch's live rows into its
+    accumulator. Its gradient and its class matrix after one update are
+    those of the dense formula: bit for bit where every row is written at
+    most once per micro-batch (``pad_random=False``: 64 slots, of which
+    only the first 16 labels x 2 neighbours can be live and are added; the
+    slots past the labels' neighbour union are masked, with id 0, and write
+    nothing), and up to the order of the additions where repeated filler
+    classes write a row twice (``pad_random=True``: all 32 slots)."""
+    problem = _prepared_problem(mesh8, "knn", "pallas", 16.0,
+                                active_frac=active_frac, knn_k=k,
+                                knn_kprime=2 * k, knn_pad_random=pad_random)
+    head = problem[3]
+    assert head.grad_rows(PREP_N // 8, PREP_B // 4) == rows
+    (l_a, m_a, g_a, w_a), (l_b, m_b, g_b, w_b) = _one_update(
+        mesh8, (head, _DenseRows(head)), problem)
+    assert l_a == l_b and m_a == m_b
+    # without fillers under half the slots hold an active class and the
+    # rest are masked; fillers take the rest, less those that hit a chosen
+    # class
+    assert (float(m_a["active_frac"]) > 0.6) == pad_random
+    if pad_random:
+        scale = np.max(np.abs(g_b))
+        np.testing.assert_allclose(g_a, g_b, rtol=0, atol=1e-6 * scale)
+        np.testing.assert_allclose(w_a, w_b, rtol=0, atol=1e-6)
+    else:
+        np.testing.assert_array_equal(g_a, g_b)
+        np.testing.assert_array_equal(w_a, w_b)
+
+
+def _loop_work(jaxpr, w_shape):
+    """In the scan bodies of a step program: broadcasts to a [V_loc, D]
+    array, scatters into the carry, scatters into anything else, and adds
+    onto a [V_loc, D] carry."""
+    found = dict(broadcast=0, scatter_carry=0, scatter_other=0, add_carry=0)
+    for eqn, _ in _eqns(jaxpr):
+        if eqn.primitive.name != "scan":
+            continue
+        body = eqn.params["jaxpr"].jaxpr
+        n_consts = eqn.params["num_consts"]
+        carry = {id(v) for v in
+                 body.invars[n_consts:n_consts + eqn.params["num_carry"]]}
+        for inner, _ in _eqns(body):
+            name = inner.primitive.name
+            if (name == "broadcast_in_dim"
+                    and inner.outvars[0].aval.shape == w_shape):
+                found["broadcast"] += 1
+            elif name.startswith("scatter"):
+                key = ("scatter_carry" if id(inner.invars[0]) in carry
+                       else "scatter_other")
+                found[key] += 1
+            elif name == "add" and any(
+                    id(v) in carry and v.aval.shape == w_shape
+                    for v in inner.invars):
+                found["add_carry"] += 1
+    return found
+
+
+def _step_jaxpr(mesh, head, problem):
+    mcfg, hcfg, tcfg, _, state, batch = problem
+    step = hybrid.make_train_step(mcfg, hcfg, tcfg, mesh, n_micro=4,
+                                  head=head, state_template=state)
+    with jax.set_mesh(mesh):
+        return jax.make_jaxpr(step)(state, batch, 0.5).jaxpr
+
+
+def test_knn_micro_batch_loop_makes_no_dense_gradient(mesh8):
+    """The knn pallas step's scan body builds no [V_loc, D] array: no zero
+    fill of that shape and no scatter but the one into the accumulator in
+    its carry. The dense form has both (the check can see them)."""
+    problem = _prepared_problem(mesh8, "knn", "pallas", 16.0)
+    w_shape = (PREP_N // 8, D)
+    head = problem[3]
+    rows = _loop_work(_step_jaxpr(mesh8, head, problem), w_shape)
+    assert rows == dict(broadcast=0, scatter_carry=1, scatter_other=0,
+                        add_carry=0)
+    dense = _loop_work(_step_jaxpr(mesh8, _DenseRows(head), problem),
+                       w_shape)
+    assert dense["broadcast"] >= 1 and dense["scatter_other"] >= 1
+    assert dense["scatter_carry"] == 0 and dense["add_carry"] == 1
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+def test_full_head_keeps_the_dense_accumulation(mesh8, backend):
+    """The full head declares no row-sparse gradient: its scan body adds
+    the dense [V_loc, D] gradient onto the carry and builds no [V_loc, D]
+    zero fill or scatter into it, and
+    its update over 4 micro-batches is the one over the whole batch in one
+    (up to the order of the additions)."""
+    problem = _prepared_problem(mesh8, "full", backend, 16.0)
+    head = problem[3]
+    assert head.grad_rows(PREP_N // 8, PREP_B // 4) == 0
+    found = _loop_work(_step_jaxpr(mesh8, head, problem), (PREP_N // 8, D))
+    assert found["add_carry"] == 1
+    assert found["broadcast"] == found["scatter_carry"] == 0
+    (l4, _, g4, w4), = _one_update(mesh8, (head,), problem, n_micro=4)
+    (l1, _, g1, w1), = _one_update(mesh8, (head,), problem, n_micro=1)
+    assert abs(l4 - l1) <= 1e-6 * abs(l1)
+    np.testing.assert_allclose(g4, g1, rtol=0,
+                               atol=1e-5 * np.max(np.abs(g1)))
+    np.testing.assert_allclose(w4, w1, rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("impl,backend,pad_random,rows", [
+    ("knn", "pallas", True, 12), ("knn", "pallas", False, 8),
+    ("knn", "ref", False, 0), ("full", "pallas", False, 0),
+    ("selective", "pallas", False, 0), ("sampled", "pallas", False, 0)])
+def test_grad_rows_declarations(impl, backend, pad_random, rows):
+    """Only the knn head on pallas declares a row-sparse class-matrix
+    gradient: of a 64-row shard's 12 active slots, every one with filler
+    classes, and without them the 1 label x 8 neighbours that can be
+    live."""
+    head = make_head(_model_cfg(PREP_N),
+                     _head_cfg(impl, backend=backend, active_frac=0.2,
+                               knn_pad_random=pad_random))
+    assert head.grad_rows(PREP_N // 8, 1) == rows

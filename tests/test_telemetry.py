@@ -336,6 +336,27 @@ def test_trainer_update_spans_hold_their_children_in_order(tiny_knn):
             "train.refresh.pack", "train.refresh.place"]
 
 
+@pytest.mark.parametrize("impl,backend,rows", [
+    ("knn", "pallas", 16), ("knn", "ref", 32), ("full", "pallas", 32)])
+def test_head_grad_rows_gauge(impl, backend, rows):
+    """``train.head_grad_rows`` reads the rows of a 32-row class-matrix
+    shard that the head's gradient writes per micro-batch: the knn head's
+    16 active slots on pallas, the whole shard where the gradient is
+    dense."""
+    from repro.api import Experiment
+    from repro.configs.base import HeadConfig
+    from repro.train import hybrid
+
+    tr = Tracer()
+    exp = Experiment.from_config(
+        system="paper", classes=64, feat_dim=16, batch=16,
+        head=HeadConfig(softmax_impl=impl, backend=backend, rebuild_every=0,
+                        knn_k=4, knn_kprime=8, active_frac=0.5),
+        mesh=hybrid.make_hybrid_mesh(2), log_every=0, telemetry=tr)
+    exp.fit(1, use_fccs_batch=False)
+    assert tr.gauges["train.head_grad_rows"] == rows
+
+
 def _echo_engine(calls, **kw):
     """An engine whose step answers each query with its own first entry
     (the request id it was made from), and records each batch's ids."""
